@@ -75,6 +75,9 @@ def run():
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
+    # A forced host mesh by design: the child stays off any accelerator
+    # (which this process may hold).
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", _PROBE],
                          capture_output=True, text=True, env=env,
                          timeout=300)

@@ -41,7 +41,7 @@ echo "== fault-matrix smoke (<240s) =="
 # must serve every request exactly once (no drops, no duplicates) with
 # the KV page pool fully reclaimed — and the sdc scenario must report
 # abft_detections > 0; the runner exits nonzero otherwise.
-timeout 240 python -m repro.launch.serve --arch mamba2-130m \
+timeout 240 python -m repro.launch.serve --arch mamba2-130m --reduced \
     --batch 2 --prompt-len 8 --gen 6 --requests 4 --fault-matrix
 
 echo "== examples: pipelined MLP + reduced end-to-end train (<420s) =="

@@ -21,7 +21,7 @@ eqn params, nothing executes), and audits the equations:
   residency (working set + out tile) fits physical VMEM before anything
   is compiled.
 
-Taint flow maps positionally through ``pjit`` boundaries (``contract``
+Taint flow maps positionally through ``jit`` boundaries (``contract``
 jits internally) and stops at ``pallas_call``: in-kernel ``select_n`` on
 the VMEM-resident panels is exactly the architected masking, so the
 kernel body is the sink, not part of the searched graph.  Backends whose
@@ -60,7 +60,7 @@ def _is_var(v) -> bool:
 
 def _sub_jaxprs(eqn):
     """Every Jaxpr hiding in an eqn's params (pallas_call kernel, scan
-    body, pjit computation, ...)."""
+    body, jit computation, ...)."""
     for v in eqn.params.values():
         for sub in (v if isinstance(v, (tuple, list)) else [v]):
             if hasattr(sub, "jaxpr"):
@@ -109,7 +109,7 @@ def _flow(jaxpr, taint: set, *, source_prims: frozenset,
 
     ``pallas_call`` is the sink: tainted operands reaching it are a hit
     iff ``flag_at_sink`` (the premask check), and its kernel body is
-    never entered.  ``pjit`` recurses with positional invar mapping
+    never entered.  ``jit`` recurses with positional invar mapping
     (``contract`` jits internally); other sub-jaxpr eqns (scan, cond)
     conservatively taint all outputs when any input is tainted.
     """
@@ -120,7 +120,7 @@ def _flow(jaxpr, taint: set, *, source_prims: frozenset,
             if tainted_in and flag_at_sink:
                 hits.append(name)
             continue
-        if name == "pjit":
+        if name == "jit":
             sub = eqn.params["jaxpr"].jaxpr
             sub_taint = {sv for v, sv in zip(eqn.invars, sub.invars)
                          if _is_var(v) and v in taint}

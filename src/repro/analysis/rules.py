@@ -191,10 +191,10 @@ DEPRECATED_SHIMS: dict[str, frozenset] = {
 }
 
 # collective-purity: the raw collective spellings (resolved through the
-# alias table, so `from jax.experimental.shard_map import shard_map` and
-# `lax.ppermute` both match) and the three modules that ARE the
-# mesh-native dispatch surface.
+# alias table, so `from jax import shard_map` and `lax.ppermute` both
+# match) and the three modules that ARE the mesh-native dispatch surface.
 COLLECTIVE_FNS = frozenset({
+    "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
     "jax.lax.with_sharding_constraint",
     "jax.lax.ppermute",

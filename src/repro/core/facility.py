@@ -74,11 +74,13 @@ class FacilityConfig:
 
     ger: Ger = Ger.BF16GER2          # activation-side GEMM family
     out_dtype: jnp.dtype = jnp.bfloat16   # activation dtype between ops
-    # Use hand-tiled Pallas kernels for GEMM-shaped contractions (TPU hot
-    # path).  Off by default because the SPMD model path wants a shardable
-    # dot_general.
-    use_pallas: bool = False
-    interpret: bool = True           # Pallas interpret mode (CPU container)
+    # Hand-tiled Pallas kernels (the TPU hot path) or the shardable XLA
+    # lowering.  None follows the platform: Pallas where the default
+    # backend is a TPU, XLA elsewhere.
+    use_pallas: bool | None = None
+    # Pallas interpret mode.  None follows the platform: interpreted only
+    # where the default backend is the CPU, compiled everywhere else.
+    interpret: bool | None = None
     # Guarded dispatch (DESIGN.md section 8): wrap contract outputs with a
     # NaN/Inf detector and demote lowering failures down the
     # pallas -> xla -> ref ladder (per-(op-class, shape) quarantine).  Off

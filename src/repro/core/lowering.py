@@ -66,7 +66,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map as _shard_map
 from jax.sharding import PartitionSpec as _P
 
 from repro.core import abft as _abft
@@ -108,7 +107,7 @@ class Plan:
 
     ger: Ger | None = None            # rank-k family; None -> config
     out_dtype: object = None          # None -> config; ACC -> acc dtype
-    backend: str | None = None        # None -> "pallas" if cfg.use_pallas
+    backend: str | None = None        # None -> config, then the platform
     epilogue: object = None           # kernels.epilogue.Epilogue | None
     block: tuple[int, int, int] | None = None   # Pallas block override
     # Accumulate forms (paper eq. 2): out = alpha * [-](X@Y) + beta * [-]C
@@ -117,7 +116,7 @@ class Plan:
     alpha: float = 1.0
     beta: float = 1.0
     saturating: bool = False          # xvi16ger2s-style clamped updates
-    interpret: bool | None = None     # None -> config (Pallas CPU mode)
+    interpret: bool | None = None     # None -> config, then the platform
     # Conv op-class only (spec is one of the canonical conv specs below):
     stride: object = 1                # int or per-spatial-dim tuple
     padding: str = "valid"            # valid | same | causal (1-D left pad)
@@ -168,6 +167,10 @@ ATTN_Q_CHUNK = 1024
 
 # Families the fused kernel accepts: float operands, f32 accumulator.
 _ATTN_GERS = (Ger.F32GER, Ger.BF16GER2, Ger.F16GER2)
+
+# Families whose kernels the TPU compiler takes (execute's dtype rule;
+# expansion hooks count as the family they chain).
+_COMPILED_GERS = (Ger.F32GER, Ger.BF16GER2)
 
 
 # ----------------------------------------------------------------------
@@ -773,6 +776,10 @@ def _lower_xla_gemm(op: Op):
     dims on the original operands, so the partitioner sees the same
     contraction ``jnp.einsum`` would have built and shards it unchanged."""
     op = _packing.demote_op(op, "xla-gemm")
+    folded = _fold_batched_n(op)
+    if folded is not None:
+        sub, unfold = folded
+        return unfold(_lower_xla_gemm(sub))
     p = op.parsed
     _sizes(p, op.x, op.y)     # label-consistency check
     passes = _passes(op.ger, op.x, op.y)
@@ -804,6 +811,40 @@ def _lower_xla_gemm(op: Op):
         prod = plain(kind, xi, yi, prod)
     # out_perm is None here (execute rejects fused/acc + permuted output)
     return _combine_expanded(op, prod, op.acc, op.residual)
+
+
+def _fold_batched_n(op: Op):
+    """Fold a batched spec's multi-label N side into one label.
+
+    The CPU runtime refuses a batched dot_general whose y operand keeps
+    two or more free dims at mixed precision ("BF16 x BF16 = F32", e.g.
+    the SSD chunk-state spec ``"bcln,bclhp->bchnp"``).  When those labels
+    sit adjacent in y, one free reshape folds them; the dot is the same
+    contraction on every backend, and the result unfolds with the spec's
+    own output permutation.  Returns ``(sub_op, unfold)`` or None."""
+    p = op.parsed
+    if (not p.batch or len(p.y_free) < 2 or op.acc is not None
+            or op.fused):
+        return None
+    at = p.y_labels.index(p.y_free[0])
+    if p.y_labels[at:at + len(p.y_free)] != p.y_free:
+        return None
+    sizes = _sizes(p, op.x, op.y)
+    f = next(c for c in "ABCDEFGHIJKLMNOPQRST"
+             if c not in p.x_labels + p.y_labels)
+    y_labels = p.y_labels[:at] + (f,) + p.y_labels[at + len(p.y_free):]
+    y = op.y.reshape(op.y.shape[:at] + (-1,)
+                     + op.y.shape[at + len(p.y_free):])
+    natural = p.batch + p.x_free + (f,)
+    sub = dataclasses.replace(op, y=y, parsed=ParsedSpec(
+        p.x_labels, y_labels, natural, p.batch, p.contract, p.x_free,
+        (f,)))
+
+    def unfold(out):
+        out = out.reshape(tuple(sizes[d] for d in p.natural_out))
+        return out if p.out_perm is None else jnp.transpose(out, p.out_perm)
+
+    return sub, unfold
 
 
 @register("xla", "gemm.masked")
@@ -1307,6 +1348,18 @@ def _attn_blocks(op: Op, bh: int, sq: int, sk: int, d: int
     return divisor(sq, 128), divisor(sk, 128)
 
 
+def _attn_tiles_fit(op: Op) -> bool:
+    """Shape rule of the compiled flash kernel.  Its head-major blocks
+    end in (rows, D), which the TPU tiling takes when rows is a multiple
+    of 8 or the whole sequence; the (1, bk) ``valid`` block further wants
+    bk a multiple of 128 or the whole Sk.  Interpret mode has no tiling."""
+    b, sq, h, d = op.x.shape
+    sk = op.y.shape[1]
+    bq, bk = _attn_blocks(op, b * h, sq, sk, d)
+    return ((bq == sq or bq % 8 == 0) and (bk == sk or bk % 8 == 0)
+            and (op.valid is None or bk == sk or bk % 128 == 0))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "kind", "block", "causal", "window", "q_offset", "interpret",
     "out_dtype", "epilogue"))
@@ -1493,11 +1546,8 @@ LADDER = ("pallas", "xla", "ref")
 # Exception classes a broken lowering legitimately raises (narrow on
 # purpose: programming errors like AttributeError must surface, not
 # demote).  InjectedFault is the fault-harness stand-in for all of them.
-_JAX_ERRORS = tuple(
-    e for e in (getattr(jax.errors, "JaxRuntimeError", None),)
-    if e is not None)
 LOWERING_ERRORS = (ValueError, TypeError, NotImplementedError,
-                   ArithmeticError) + _JAX_ERRORS
+                   ArithmeticError, jax.errors.JaxRuntimeError)
 
 _QUARANTINE: dict[tuple, str] = {}     # guard key -> demoted start rung
 GUARD_EVENTS: list[dict] = []          # demotion log (tests/CI assert)
@@ -1887,14 +1937,17 @@ def _plan_attn_shards(op: Op, rules) -> _ShardPlan | None:
         return None
 
     # The global (bq, bk) plan; a sequence shard takes the largest
-    # divisor of its local Sq not above the global bq (the kernel wants
-    # dividing query blocks; bk is untouched — it shapes the KV stream
-    # every shard walks identically).
+    # divisor of its local Sq not above the global bq that the TPU tiling
+    # takes (the kernel wants dividing query blocks; bk is untouched — it
+    # shapes the KV stream every shard walks identically).  No such
+    # divisor: the dispatch stays single-device.
     bq, bk = _attn_blocks(op, b * h, sq, sk, d)
     if q[1] is not None:
         loc = sq // rules.axis_extent(sqp)
-        while loc % bq:
-            bq -= 1
+        bq = next((c for c in range(min(bq, loc), 0, -1)
+                   if loc % c == 0 and (c % 8 == 0 or c == loc)), None)
+        if bq is None:
+            return None
     valid_spec = _P()
     if (op.valid is not None and q[0] is not None
             and getattr(op.valid, "ndim", 0) == 2
@@ -1960,9 +2013,9 @@ def _shard_wrap(sp: _ShardPlan):
                         return lax.switch(idx, branches)
                     return fn(inner)
 
-            return _shard_map(
+            return jax.shard_map(
                 body, mesh=sp.mesh, in_specs=tuple(specs),
-                out_specs=sp.out_spec, check_rep=False)(*vals)
+                out_specs=sp.out_spec, check_vma=False)(*vals)
         return run
     return wrap
 
@@ -2062,10 +2115,19 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
         out_dtype = pol.acc_dtype
     else:
         out_dtype = plan.out_dtype or cfg.out_dtype
-    backend = plan.backend or ("pallas" if cfg.use_pallas else "xla")
+    # Unset FacilityConfig fields follow the platform (the one place they
+    # resolve): compiled Pallas kernels on a TPU, XLA elsewhere, and Pallas
+    # interpret mode only on the CPU.  Plan fields override both.
+    platform = jax.default_backend()
+    use_pallas = (platform == "tpu" if cfg.use_pallas is None
+                  else cfg.use_pallas)
+    backend = plan.backend or ("pallas" if use_pallas else "xla")
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; have {BACKENDS}")
-    interpret = cfg.interpret if plan.interpret is None else plan.interpret
+    interpret = plan.interpret
+    if interpret is None:
+        interpret = (platform == "cpu" if cfg.interpret is None
+                     else cfg.interpret)
 
     ep = plan.epilogue
     if ep is None:
@@ -2222,6 +2284,12 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
         # (gemm.saturating precedent).  Depthwise no longer reroutes: it
         # runs the resident-accumulator VPU kernel (mma_conv).
         backend = "xla"
+    if (backend == "pallas" and not interpret
+            and rep_kind(ger) not in _COMPILED_GERS):
+        # Dtype rule: the TPU compiler refuses the kernels' f64, f16 and
+        # integer families (v5e), so compiled dispatches of those take the
+        # XLA lowering; interpret mode runs every family.
+        backend = "xla"
 
     fn = lookup(backend, op_class, ger, not ep.is_identity)
     if fn is None and backend == "pallas":
@@ -2253,6 +2321,13 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
             stride=stride, padding=plan.padding, masks=masks,
             z=z, valid=valid, causal=plan.causal, window=plan.window,
             q_offset=plan.q_offset, q_chunk=plan.q_chunk)
+    if (op_class == "attn" and backend == "pallas" and not interpret
+            and not _attn_tiles_fit(op)):
+        # Shape rule: attn block plans the TPU tiling refuses (see
+        # _attn_tiles_fit) take the XLA lowering, before counting.
+        backend = "xla"
+        fn = lookup(backend, op_class, ger, not ep.is_identity)
+        op = dataclasses.replace(op, backend=backend)
     wrap = None
     if backend == "pallas" and op_class in ("gemm", "conv", "attn"):
         srules = _shard_rules(plan)

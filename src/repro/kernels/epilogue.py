@@ -32,13 +32,41 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+# erf(x) = x * P(x^2) / Q(x^2) with x clamped to [-c, c], beyond which
+# f32 erf rounds to +-1: the rational form XLA's CPU backend uses for f32
+# erf, so on the CPU it equals jax.lax.erf bit for bit.  Pallas TPU has
+# no lowering for lax.erf.
+_ERF_P = (0.00022905065861350646, 0.0034082910107109506,
+          0.050955695062380861, 0.18520832239976145, 1.128379143519084)
+_ERF_Q = (-1.1791602954361697e-7, 0.000023547966471313185,
+          0.0010179625278914885, 0.014070470171167667,
+          0.11098505178285362, 0.49746925110067538, 1.0)
+_ERF_CLAMP = 3.7439211627767994
+
+
+def erf(v):
+    """Error function from mul/add/div only, so it lowers in every
+    kernel.  f64 keeps ``jax.lax.erf`` (the VPU/interpret path only)."""
+    if v.dtype == jnp.float64:
+        return jax.lax.erf(v)
+    x = jnp.clip(v.astype(jnp.float32), -_ERF_CLAMP, _ERF_CLAMP)
+    x2 = x * x
+    p = jnp.full_like(x2, _ERF_P[0])
+    for c in _ERF_P[1:]:
+        p = p * x2 + c
+    q = jnp.full_like(x2, _ERF_Q[0])
+    for c in _ERF_Q[1:]:
+        q = q * x2 + c
+    return (x * p / q).astype(v.dtype)
+
+
 def _gelu_exact(v):
     # Exact (erf) gelu, not the tanh approximation: the tanh form's
     # x + 0.044715*x^3 term FMA-contracts differently inside a fused kernel
     # than in an eager reference, breaking the bit-for-bit contract below.
     half = jnp.asarray(0.5, v.dtype)
     inv_sqrt2 = jnp.asarray(0.7071067811865476, v.dtype)
-    return v * (half * (1.0 + jax.lax.erf(v * inv_sqrt2)))
+    return v * (half * (1.0 + erf(v * inv_sqrt2)))
 
 
 ACTIVATIONS = {

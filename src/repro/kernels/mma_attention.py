@@ -141,9 +141,9 @@ def _flash_kernel(maps_ref, *refs, bq: int, bk: int, causal: bool,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :]                            # (bq, d)
-    k = k_ref[0, :, 0, :]                            # (bk, d)
-    v = v_ref[0, :, 0, :]                            # (bk, d)
+    q = q_ref[...]                                   # (bq, d)
+    k = k_ref[...]                                   # (bk, d)
+    v = v_ref[...]                                   # (bk, d)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -159,7 +159,7 @@ def _flash_kernel(maps_ref, *refs, bq: int, bk: int, causal: bool,
             live &= q_pos - k_pos < window
         s = jnp.where(live, s, NEG_INF)
     if valid_ref is not None:
-        s = jnp.where(valid_ref[...], s, NEG_INF)    # (1, bk) broadcast
+        s = jnp.where(valid_ref[...] != 0, s, NEG_INF)   # (1, bk) broadcast
 
     m_prev = m_ref[...]                              # (bq, 1)
     m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -185,9 +185,8 @@ def _flash_kernel(maps_ref, *refs, bq: int, bk: int, causal: bool,
             out = _epilogue.apply(
                 out, ep,
                 bias=bias_ref[...] if bias_ref is not None else None,
-                residual=res_ref[0, :, 0, :] if res_ref is not None
-                else None)
-        out_ref[0, :, 0, :] = out.astype(out_ref.dtype)
+                residual=res_ref[...] if res_ref is not None else None)
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 def mma_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
@@ -247,27 +246,27 @@ def mma_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         window=window, sm_scale=sm_scale, has_valid=valid is not None,
         ep=ep)
 
-    in_specs = [
-        pl.BlockSpec((1, bq, 1, d), lambda bb, hh, t, m: (bb, m[0, t], hh, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda bb, hh, t, m: (bb, m[1, t], hh // group, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda bb, hh, t, m: (bb, m[1, t], hh // group, 0)),
-    ]
-    inputs = [q, k, v]
+    # The kernel runs head-major: (B, H, S, D) operands, so the head axis
+    # is a squeezed block dim and each block's last two dims are (rows, D)
+    # — the TPU tiling wants those divisible by (8, 128) or whole.
+    q_blk = pl.BlockSpec((None, None, bq, d),
+                         lambda bb, hh, t, m: (bb, hh, m[0, t], 0))
+    kv_blk = pl.BlockSpec((None, None, bk, d),
+                          lambda bb, hh, t, m: (bb, hh // group, m[1, t], 0))
+    in_specs = [q_blk, kv_blk, kv_blk]
+    inputs = [q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2)]
     if valid is not None:
-        valid = jnp.broadcast_to(jnp.asarray(valid, jnp.bool_)
-                                 .reshape(-1, sk), (b, sk))
+        valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32)
+                                 .reshape(-1, 1, sk), (b, 1, sk))
         in_specs.append(pl.BlockSpec(
-            (1, bk), lambda bb, hh, t, m: (bb, m[1, t])))
+            (None, 1, bk), lambda bb, hh, t, m: (bb, 0, m[1, t])))
         inputs.append(valid)
     if ep is not None and ep.bias:
         in_specs.append(pl.BlockSpec((1, d), lambda bb, hh, t, m: (0, 0)))
         inputs.append(bias.reshape(1, d))
     if ep is not None and ep.residual:
-        in_specs.append(pl.BlockSpec(
-            (1, bq, 1, d), lambda bb, hh, t, m: (bb, m[0, t], hh, 0)))
-        inputs.append(residual)
+        in_specs.append(q_blk)
+        inputs.append(residual.swapaxes(1, 2))
 
     return pl.pallas_call(
         kernel,
@@ -275,18 +274,17 @@ def mma_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, bq, 1, d), lambda bb, hh, t, m: (bb, m[0, t], hh, 0)),
+            out_specs=q_blk,
             scratch_shapes=[
                 pltpu.VMEM((bq, d), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
                 pltpu.VMEM((bq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d),
                                        out_dtype or q.dtype),
         interpret=interpret,
-    )(maps, *inputs)
+    )(maps, *inputs).swapaxes(1, 2)
 
 
 # ----------------------------------------------------------------------

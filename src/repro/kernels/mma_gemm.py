@@ -132,11 +132,20 @@ def _make_kernel(*, pol, k_steps, k_size, bk_logical, neg_product, neg_acc,
         # panels are masked — out-of-bounds reads are undefined (NaN in
         # interpret mode) and 0 * NaN would poison the accumulator.
         # (m/n fringe is handled by Pallas dropping out-of-bounds stores.)
+        # The predicates take each panel's full shape.  Where X has fewer
+        # than 8 rows and a packed sub-32-bit dtype, its panel is selected
+        # in f32, which holds every such value exactly: Mosaic cannot lay
+        # the predicate over that panel ("Sublane broadcast").
         if k_steps * bk_logical != k_size:
-            kk = ki * bk_logical + jax.lax.broadcasted_iota(
-                jnp.int32, (1, x.shape[1]), 1)
-            x = jnp.where(kk < k_size, x, jnp.zeros_like(x))
-            y = jnp.where(kk.reshape(-1, 1) < k_size, y, jnp.zeros_like(y))
+            kx = ki * bk_logical + jax.lax.broadcasted_iota(
+                jnp.int32, x.shape, 1)
+            ky = ki * bk_logical + jax.lax.broadcasted_iota(
+                jnp.int32, y.shape, 0)
+            widen = m_size < 8 and jnp.dtype(x.dtype).itemsize < 4
+            xs = x.astype(jnp.float32) if widen else x
+            x = jnp.where(kx < k_size, xs, jnp.zeros_like(xs)).astype(
+                x.dtype)
+            y = jnp.where(ky < k_size, y, jnp.zeros_like(y))
         if jnp.issubdtype(pol.acc_dtype, jnp.integer):
             x = x.astype(jnp.int32)
             y = y.astype(jnp.int32)

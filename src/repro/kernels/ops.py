@@ -63,7 +63,7 @@ def mma_dot(x: jnp.ndarray, y: jnp.ndarray,
             c: jnp.ndarray | None = None, *,
             kind: Ger = Ger.BF16GER2,
             block: tuple[int, int, int] | None = None,
-            use_pallas: bool = True, interpret: bool = True,
+            use_pallas: bool = True, interpret: bool | None = None,
             out_dtype=None) -> jnp.ndarray:
     """Deprecated: ``facility.contract("mk,kn->mn", x, y, acc=c,
     plan=Plan(ger=kind, ...))``.
@@ -86,7 +86,7 @@ def mma_dot_fused(x: jnp.ndarray, y: jnp.ndarray,
                   bias: jnp.ndarray | None = None,
                   residual: jnp.ndarray | None = None,
                   block: tuple[int, int, int] | None = None,
-                  use_pallas: bool = True, interpret: bool = True,
+                  use_pallas: bool = True, interpret: bool | None = None,
                   neg_product: bool = False, neg_acc: bool = False,
                   alpha: float = 1.0, beta: float = 1.0,
                   out_dtype=None) -> jnp.ndarray:
@@ -125,7 +125,7 @@ def mma_ger_saturating(x: jnp.ndarray, y: jnp.ndarray,
 
 
 def mma_pm_dot(x, y, *, kind: Ger, xmask, ymask, pmask=None, acc=None,
-               use_pallas: bool = True, interpret: bool = True):
+               use_pallas: bool = True, interpret: bool | None = None):
     """Deprecated: ``facility.contract("mk,kn->mn", x, y, masks=(xmask,
     ymask, pmask), plan=Plan(ger=kind, ...))``.
 
@@ -149,7 +149,7 @@ def mma_pm_dot(x, y, *, kind: Ger, xmask, ymask, pmask=None, acc=None,
 
 
 def mma_conv2d(image, kernels, *, use_pallas: bool = True,
-               interpret: bool = True, bf: int | None = None):
+               interpret: bool | None = None, bf: int | None = None):
     """Deprecated: ``facility.contract(facility.CONV2D, image, kernels,
     plan=Plan(ger=Ger.F32GER, backend=..., stride=..., padding=...))``.
 

@@ -1,7 +1,9 @@
 """Fault-tolerant batched serving runtime (DESIGN.md section 8).
 
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-130m \
-        --reduced --batch 4 --prompt-len 32 --gen 16
+        [--reduced] --batch 4 --prompt-len 32 --gen 16
+
+Without ``--reduced`` the architecture is served at its published config.
 
 Continuous batching at step granularity, rebuilt around three runtime
 pieces the original loop lacked:
@@ -55,6 +57,7 @@ from repro.configs import get as get_arch, ARCHS
 from repro.configs.base import reduced as reduce_cfg
 from repro.core import abft as _abft
 from repro.core import facility, lowering
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.runtime import faults as _faults
 from repro.runtime.kv_pages import PagePool, PagesExhausted
@@ -467,7 +470,10 @@ def run_fault_matrix(cfg, params, *, batch=2, prompt_len=8, gen_len=6,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, default="mamba2-130m")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config "
+                         "(configs.base.reduced) instead of the published "
+                         "one")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -489,6 +495,7 @@ def main():
                     help="run the seeded fault-injection matrix instead "
                          "of a plain serving run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -11,6 +11,7 @@ full configs — the mesh and shardings are the only difference.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -20,9 +21,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get as get_arch, ARCHS
 from repro.configs.base import reduced as reduce_cfg
+from repro.core import facility
 from repro.data import pipeline
-from repro.launch import mesh as mesh_lib
-from repro.models import model as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw, schedule
 from repro.parallel import api as par
 from repro.runtime.elastic import ElasticTrainer, ElasticConfig
@@ -62,7 +63,11 @@ def build(cfg, *, mesh=None, lr=3e-4, total_steps=1000, grad_accum=1,
 
     def make_step():
         def run(state, batch):
-            with par.use_rules(rules):
+            # The Pallas kernels have no backward pass yet (no custom_vjp),
+            # so the training step traces every contract on the XLA
+            # lowering, whatever the platform would pick for inference.
+            xla = dataclasses.replace(facility.current(), use_pallas=False)
+            with par.use_rules(rules), facility.configure(xla):
                 return jstep(state, batch)
         return run
 
@@ -82,6 +87,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -217,8 +217,12 @@ def apply_mamba2(p, x, cfg, state=None):
         new_state = {"ssm": sstate, "conv": conv_state}
 
     y = y.reshape(b, l, d_in)
-    # gated RMSNorm (mamba2 block output norm)
+    # gated RMSNorm (mamba2 block output norm).  Its mean runs over all of
+    # d_in on one device: a mesh-sharded d_in would be summed as per-shard
+    # partials plus an all-reduce, which rounds differently from one
+    # device's sum.
     g = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    g = shard(g, "batch", None, None)
     gf = g.astype(jnp.float32)
     g = (gf * jax.lax.rsqrt((gf * gf).mean(-1, keepdims=True) + cfg.norm_eps)
          * p["norm_scale"]).astype(x.dtype)

@@ -23,7 +23,6 @@ from typing import Optional
 
 import jax
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -233,7 +232,7 @@ def expert_exchange(buf, params, fn):
         return lax.all_to_all(out, name, split_axis=1, concat_axis=0,
                               tiled=True)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=r.mesh,
         in_specs=(P(None, ax), P(ax)), out_specs=P(None, ax),
-        check_rep=False)(buf, params)
+        check_vma=False)(buf, params)

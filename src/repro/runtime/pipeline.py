@@ -34,7 +34,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import facility
 from repro.runtime import faults as _faults
@@ -105,10 +104,10 @@ def pipeline_apply(stage_fn: Callable, params, x, *, mesh: Mesh,
             return outs.reshape(-1, *outs.shape[2:])
 
         pspec_params = jax.tree.map(lambda _: P(axis), params)
-        return shard_map(
+        return jax.shard_map(
             per_device, mesh=mesh,
             in_specs=(pspec_params, P()), out_specs=P(),
-            check_rep=False)(params, xin)
+            check_vma=False)(params, xin)
 
     if on_chunk is None:
         return run(x, mb)

@@ -232,7 +232,7 @@ def test_collective_purity():
     # raw collectives outside the mesh-native dispatch surface: every
     # spelling (module attr chain, lax alias, from-import) is a finding
     assert "collective-purity" in rule_ids("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         def f(fn, mesh, x):
             return shard_map(fn, mesh=mesh)(x)
     """)
@@ -252,7 +252,7 @@ def test_collective_purity():
                  "src/repro/core/lowering.py",
                  "src/repro/runtime/pipeline.py"):
         assert "collective-purity" not in rule_ids("""
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax import lax
             def f(fn, mesh, x):
                 return shard_map(fn, mesh=mesh)(lax.ppermute(x, 'a', []))

@@ -75,13 +75,26 @@ def test_gemm_int4_packed(rng):
 
 def test_gemm_fp64_interpret(rng):
     """The paper's DGEMM case study dtype (VPU path on TPU)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(rng.normal(size=(64, 128)), jnp.float64)
         y = jnp.asarray(rng.normal(size=(128, 128)), jnp.float64)
         got = K.mma_gemm(x, y, kind=Ger.F64GER, block=(32, 128, 128),
                          interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(x) @
                                    np.asarray(y), rtol=1e-12)
+
+
+def test_gemm_fp64_interpret_short_m_k_fringe(rng):
+    """Fewer than 8 rows of X and a K fringe: the fringe mask keeps the
+    f64 panels at f64."""
+    with jax.enable_x64(True):
+        x = jnp.asarray(rng.normal(size=(4, 200)), jnp.float64)
+        y = jnp.asarray(rng.normal(size=(200, 128)), jnp.float64)
+        got = K.mma_gemm(x, y, kind=Ger.F64GER, block=(8, 128, 128),
+                         interpret=True)
+        # f64 rounding of a 200-term sum; an f32 panel errs by ~1e-6
+        np.testing.assert_allclose(np.asarray(got), np.asarray(x) @
+                                   np.asarray(y), rtol=1e-12, atol=1e-12)
 
 
 @pytest.mark.parametrize("neg_product,neg_acc", [(False, False),

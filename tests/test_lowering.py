@@ -97,7 +97,7 @@ def test_gemm_backends_agree(kind, rng):
         return outs
 
     if kind == Ger.F64GER:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             outs = run()
             ref = outs.pop("ref")
             for b, got in outs.items():
@@ -617,7 +617,7 @@ def test_depthwise_non_f32_acc_still_reroutes_to_xla(rng):
     x = jnp.asarray(rng.normal(size=(1, 8, 4)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(3, 4)), jnp.float32)
     lowering.DISPATCH_COUNTS.clear()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         facility.contract(
             facility.CONV1D_DEPTHWISE, x.astype(jnp.float64),
             w.astype(jnp.float64),
@@ -1474,3 +1474,53 @@ def test_mma_pm_dot_shim_routes_through_gemm_masked(rng):
     want = ref.pm_ger(x, y, Ger.BF16GER2, xm, ym, pm)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", [Ger.I8GER4, Ger.F16GER2])
+def test_compiled_pallas_routes_refused_families_to_xla(kind, rng):
+    """Dtype rule: a compiled (not interpreted) Pallas dispatch of a family
+    the TPU compiler refuses takes the XLA lowering, before counting."""
+    pol = policy(kind)
+    if jnp.issubdtype(pol.x_dtype, jnp.integer):
+        x = jnp.asarray(rng.integers(-50, 50, (16, 64)), pol.x_dtype)
+        y = jnp.asarray(rng.integers(0, 200, (64, 128)), pol.y_dtype)
+    else:
+        x = jnp.asarray(rng.normal(size=(16, 64)), pol.x_dtype)
+        y = jnp.asarray(rng.normal(size=(64, 128)), pol.y_dtype)
+    lowering.DISPATCH_COUNTS.clear()
+    got = facility.contract("mk,kn->mn", x, y, plan=Plan(
+        ger=kind, backend="pallas", interpret=False, out_dtype=lowering.ACC))
+    assert lowering.DISPATCH_COUNTS == {("xla", "gemm", kind.value): 1}
+    want = facility.contract("mk,kn->mn", x, y, plan=Plan(
+        ger=kind, backend="xla", out_dtype=lowering.ACC))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("sk,valid,routed", [
+    (1500, False, True),    # bk = 125: not a multiple of 8
+    (200, True, True),      # bk = 100 with a valid mask: not 128-aligned
+    (100, True, False),     # bk = Sk: the whole row is always tileable
+], ids=["bk125", "valid-bk100", "valid-whole-sk"])
+def test_attn_tiling_rule_routes_unaligned_blocks(sk, valid, routed, rng):
+    """Shape rule: compiled Pallas attention whose resolved blocks the TPU
+    tiling refuses takes the XLA lowering; interpret mode keeps Pallas."""
+    q = jnp.asarray(rng.normal(size=(2, 8, 2, 16)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, sk, 2, 16)), jnp.float32)
+    masks = (jnp.asarray(rng.random((2, sk)) > 0.3),) if valid else None
+    for interpret in (False, True):
+        lowering.DISPATCH_COUNTS.clear()
+
+        def call():
+            return facility.contract(
+                facility.ATTN, q, k, k, masks=masks,
+                plan=Plan(ger=Ger.F32GER, backend="pallas",
+                          interpret=interpret))
+
+        if interpret or routed:
+            call()
+        else:
+            with pytest.raises(Exception):   # no compiled Pallas on a CPU
+                call()
+        want = "xla" if routed and not interpret else "pallas"
+        assert lowering.DISPATCH_COUNTS[
+            (want, "attn", Ger.F32GER.value)] == 1

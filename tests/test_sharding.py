@@ -19,6 +19,7 @@ def _run(py_src: str, n_dev: int = 8, timeout=1200):
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_dev} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"      # a forced host mesh, never a chip
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(py_src)],
                          capture_output=True, text=True, env=env,
                          timeout=timeout)
