@@ -1,0 +1,7 @@
+"""Needed model FLOPs over the traced window, as a share of the bf16 peak
+(score cells)."""
+from benchlib import readers
+
+
+def read(ctx):
+    return readers.mfu(ctx, "score")
