@@ -92,6 +92,14 @@ ACC = "acc"
 DISPATCH_COUNTS: collections.Counter = collections.Counter()
 
 
+def _dispatch_scope(op_class: str, backend: str):
+    """The name every device op of one dispatch carries in its HLO
+    ``op_name``, forward and backward alike:
+    ``contract.<op_class>.<backend>``, naming the backend that ran, as the
+    ``DISPATCH_COUNTS`` key does."""
+    return jax.named_scope(f"contract.{op_class}.{backend}")
+
+
 # ----------------------------------------------------------------------
 # Plan: the architected call signature of the builtin
 # ----------------------------------------------------------------------
@@ -1657,13 +1665,14 @@ def _guarded_dispatch(op: "Op", op_class: str, backend: str, ger: Ger,
         fault = _faults.maybe_inject(_faults.CONTRACT_DISPATCH)
         runner = wrap(fn) if wrap is not None else fn
         cap = None
-        if aplan is not None and aplan.augments:
-            raw = runner(aplan.augment(sub))
-        elif aplan is not None:
-            with _abft.capture() as cap:
+        with _dispatch_scope(op_class, sub.backend):
+            if aplan is not None and aplan.augments:
+                raw = runner(aplan.augment(sub))
+            elif aplan is not None:
+                with _abft.capture() as cap:
+                    raw = runner(sub)
+            else:
                 raw = runner(sub)
-        else:
-            raw = runner(sub)
         raw = _apply_data_fault(fault, raw)
         out = aplan.strip(raw) if aplan is not None and aplan.augments \
             else raw
@@ -2346,7 +2355,8 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
         # output (tests/test_guards.py::test_guards_off_bitwise_unchanged).
         DISPATCH_COUNTS[(backend, op_class, ger.value)] += 1
         fault = _faults.maybe_inject(_faults.CONTRACT_DISPATCH)
-        out = wrap(fn)(op) if wrap is not None else fn(op)
+        with _dispatch_scope(op_class, backend):
+            out = wrap(fn)(op) if wrap is not None else fn(op)
         out = _apply_data_fault(fault, out)
     if dequant is not None:
         out = dequant.apply(out)

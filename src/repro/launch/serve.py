@@ -107,9 +107,10 @@ def _scatter_prefill(cache, pre, slot, cfg):
     scatter unsound — documented limitation)."""
     if "ssm" in pre and "ssm" in cache and "k" not in cache:
         cache = dict(cache)
-        cache["ssm"] = cache["ssm"].at[:, slot].set(pre["ssm"][:, 0])
-        cache["conv"] = cache["conv"].at[:, slot].set(
-            pre["conv"][:, 0].astype(cache["conv"].dtype))
+        with jax.named_scope("serve.handoff"):
+            cache["ssm"] = cache["ssm"].at[:, slot].set(pre["ssm"][:, 0])
+            cache["conv"] = cache["conv"].at[:, slot].set(
+                pre["conv"][:, 0].astype(cache["conv"].dtype))
     return cache
 
 

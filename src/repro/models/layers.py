@@ -45,16 +45,17 @@ def norm_axes(cfg, d=None):
 
 
 def apply_norm(p, x, cfg):
-    xf = x.astype(jnp.float32)
-    if cfg.norm == "layernorm":
-        mu = xf.mean(-1, keepdims=True)
-        var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-        out = (xf - mu) * jax.lax.rsqrt(var + cfg.norm_eps)
-        out = out * p["scale"] + p["bias"]
-    else:
-        var = (xf * xf).mean(-1, keepdims=True)
-        out = xf * jax.lax.rsqrt(var + cfg.norm_eps) * p["scale"]
-    return out.astype(x.dtype)
+    with jax.named_scope("block.norm"):
+        xf = x.astype(jnp.float32)
+        if cfg.norm == "layernorm":
+            mu = xf.mean(-1, keepdims=True)
+            var = ((xf - mu) ** 2).mean(-1, keepdims=True)
+            out = (xf - mu) * jax.lax.rsqrt(var + cfg.norm_eps)
+            out = out * p["scale"] + p["bias"]
+        else:
+            var = (xf * xf).mean(-1, keepdims=True)
+            out = xf * jax.lax.rsqrt(var + cfg.norm_eps) * p["scale"]
+        return out.astype(x.dtype)
 
 
 # ----------------------------------------------------------------------
@@ -315,10 +316,16 @@ def embed_axes(cfg):
 
 
 def embed_tokens(p, tokens, cfg, dtype=jnp.bfloat16):
-    return p["tok"].astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        with jax.named_scope("weights.cast"):
+            table = p["tok"].astype(dtype)
+        return table[tokens]
 
 
 def logits(p, x, cfg):
-    w = (p["tok"].T if cfg.tie_embeddings else p["unembed"])
-    return facility.contract(DOT, x, w.astype(x.dtype),
-                             plan=Plan(out_dtype=jnp.float32))
+    with jax.named_scope("head"):
+        w = (p["tok"].T if cfg.tie_embeddings else p["unembed"])
+        with jax.named_scope("weights.cast"):
+            w = w.astype(x.dtype)
+        return facility.contract(DOT, x, w,
+                                 plan=Plan(out_dtype=jnp.float32))

@@ -76,26 +76,28 @@ def _causal_conv(xbc, conv_w, conv_b, conv_state=None):
     epilogue contract; F32GER keeps the tap products in f32, matching the
     old hand-rolled shift-and-sum numerics.
     """
-    w = conv_w.shape[0]
-    if conv_state is not None:
-        xin = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
-        padding = "valid"
-    else:
-        xin = xbc
-        padding = "causal"
-    out = facility.contract(
-        facility.CONV1D_DEPTHWISE, xin, conv_w, bias=conv_b,
-        plan=Plan(ger=Ger.F32GER, padding=padding,
-                  epilogue=Epilogue(bias=True, activation="silu"),
-                  out_dtype=xbc.dtype))
-    if conv_state is not None:
-        return out, xin[:, -(w - 1):, :]
-    # New history = last W-1 input frames, zero-prefixed for short seqs
-    # (the causal padding itself stays inside the conv lowering).
-    l = xbc.shape[1]
-    state = (xbc[:, -(w - 1):, :] if l >= w - 1
-             else jnp.pad(xbc, ((0, 0), (w - 1 - l, 0), (0, 0))))
-    return out, state
+    with jax.named_scope("ssm.conv"):
+        w = conv_w.shape[0]
+        if conv_state is not None:
+            xin = jnp.concatenate([conv_state.astype(xbc.dtype), xbc],
+                                  axis=1)
+            padding = "valid"
+        else:
+            xin = xbc
+            padding = "causal"
+        out = facility.contract(
+            facility.CONV1D_DEPTHWISE, xin, conv_w, bias=conv_b,
+            plan=Plan(ger=Ger.F32GER, padding=padding,
+                      epilogue=Epilogue(bias=True, activation="silu"),
+                      out_dtype=xbc.dtype))
+        if conv_state is not None:
+            return out, xin[:, -(w - 1):, :]
+        # New history = last W-1 input frames, zero-prefixed for short
+        # seqs (the causal padding itself stays inside the conv lowering).
+        l = xbc.shape[1]
+        state = (xbc[:, -(w - 1):, :] if l >= w - 1
+                 else jnp.pad(xbc, ((0, 0), (w - 1 - l, 0), (0, 0))))
+        return out, state
 
 
 def _segsum(dA):
@@ -147,7 +149,8 @@ def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
 
     def step(carry, inp):
         st, dec = inp                                     # (b,h,n,p), (b,h)
-        new = carry * dec[..., None, None] + st
+        with jax.named_scope("ssm.state"):
+            new = carry * dec[..., None, None] + st
         return new, carry                                  # emit *previous*
 
     init = jnp.zeros((b, h, n, p), jnp.float32)
@@ -205,12 +208,14 @@ def apply_mamba2(p, x, cfg, state=None):
         # single-token recurrent update: s <- exp(dt A) s + dt B x
         dA = jnp.exp(dt[:, 0] * A)                        # (b,h)
         sstate = state["ssm"]                             # (b,h,n,p)
-        upd = facility.contract("bn,bhp->bhnp", B[:, 0],
-                                (xh[:, 0] * dt[:, 0, :, None]).astype(x.dtype),
-                                plan=Plan(out_dtype=jnp.float32))
-        sstate = sstate * dA[..., None, None] + upd
-        y = facility.contract("bn,bhnp->bhp", C[:, 0],
-                              sstate.astype(x.dtype))
+        with jax.named_scope("ssm.state"):
+            upd = facility.contract(
+                "bn,bhp->bhnp", B[:, 0],
+                (xh[:, 0] * dt[:, 0, :, None]).astype(x.dtype),
+                plan=Plan(out_dtype=jnp.float32))
+            sstate = sstate * dA[..., None, None] + upd
+            y = facility.contract("bn,bhnp->bhp", C[:, 0],
+                                  sstate.astype(x.dtype))
         y = (y.astype(jnp.float32)
              + xh[:, 0].astype(jnp.float32) * p["D"][:, None])
         y = y[:, None].astype(x.dtype)

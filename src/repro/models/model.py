@@ -250,51 +250,58 @@ def _residual_shard(h):
 def _apply_dense_block(bp, h, cfg, *, cos_sin, is_moe, causal=None,
                        cross_x=None, kv=None, window=None, q_offset=0,
                        kv_positions=None, valid=None):
-    hn = L.apply_norm(bp["attn_norm"], h, cfg)
-    # Residual adds ride the output-projection / w2 GEMM epilogues
-    # (layers.apply_attention / apply_mlp `residual=`): one fused store
-    # instead of a separate read-modify-write of the activations.
-    a, kv_out = L.apply_attention(
-        bp["attn"], hn, cfg, cos_sin=cos_sin, kv=kv, causal=causal,
-        window=window, q_offset=q_offset, kv_positions=kv_positions,
-        valid=valid, residual=h)
-    h = _residual_shard(a)
-    aux = jnp.zeros((), jnp.float32)
-    cross_kv = None
-    if cross_x is not None and "cross" in bp:
-        hn = L.apply_norm(bp["cross_norm"], h, cfg)
-        ca, cross_kv = L.apply_attention(bp["cross"], hn, cfg, causal=False,
-                                         cross_x=cross_x, residual=h)
-        h = _residual_shard(ca)
-    hn = L.apply_norm(bp["mlp_norm"], h, cfg)
-    if is_moe:
-        m, aux = MOE.apply_moe(bp["moe"], hn, cfg)
-        h = _residual_shard(h + m)
-    else:
-        h = _residual_shard(L.apply_mlp(bp["mlp"], hn, cfg, residual=h))
+    with jax.named_scope("block.attn"):
+        hn = L.apply_norm(bp["attn_norm"], h, cfg)
+        # Residual adds ride the output-projection / w2 GEMM epilogues
+        # (layers.apply_attention / apply_mlp `residual=`): one fused store
+        # instead of a separate read-modify-write of the activations.
+        a, kv_out = L.apply_attention(
+            bp["attn"], hn, cfg, cos_sin=cos_sin, kv=kv, causal=causal,
+            window=window, q_offset=q_offset, kv_positions=kv_positions,
+            valid=valid, residual=h)
+        h = _residual_shard(a)
+        aux = jnp.zeros((), jnp.float32)
+        cross_kv = None
+        if cross_x is not None and "cross" in bp:
+            hn = L.apply_norm(bp["cross_norm"], h, cfg)
+            ca, cross_kv = L.apply_attention(bp["cross"], hn, cfg,
+                                             causal=False, cross_x=cross_x,
+                                             residual=h)
+            h = _residual_shard(ca)
+    with jax.named_scope("block.mlp"):
+        hn = L.apply_norm(bp["mlp_norm"], h, cfg)
+        if is_moe:
+            m, aux = MOE.apply_moe(bp["moe"], hn, cfg)
+            h = _residual_shard(h + m)
+        else:
+            h = _residual_shard(L.apply_mlp(bp["mlp"], hn, cfg, residual=h))
     return h, aux, kv_out, cross_kv
 
 
 def _apply_ssm_block(bp, h, cfg, state=None):
-    hn = L.apply_norm(bp["norm"], h, cfg)
-    out, new_state = M2.apply_mamba2(bp["mamba"], hn, cfg, state=state)
-    return _residual_shard(h + out), new_state
+    with jax.named_scope("block.ssm"):
+        hn = L.apply_norm(bp["norm"], h, cfg)
+        out, new_state = M2.apply_mamba2(bp["mamba"], hn, cfg, state=state)
+        return _residual_shard(h + out), new_state
 
 
 def _apply_shared_attn(sp, h, emb0, cfg, *, cos_sin, kv=None, q_offset=0,
                        kv_positions=None, valid=None):
     """zamba2 shared block: operates on concat(h, original embedding)."""
     from repro.core import facility
-    hin = facility.contract(facility.DOT,
-                            jnp.concatenate([h, emb0], axis=-1),
-                            sp["in_proj"])
-    hn = L.apply_norm(sp["attn_norm"], hin, cfg)
-    a, kv_out = L.apply_attention(sp["attn"], hn, cfg, cos_sin=cos_sin,
-                                  kv=kv, q_offset=q_offset,
-                                  kv_positions=kv_positions, valid=valid)
-    hin = hin + a
-    m = L.apply_mlp(sp["mlp"], L.apply_norm(sp["mlp_norm"], hin, cfg), cfg)
-    return _residual_shard(h + hin + m)
+    with jax.named_scope("block.attn"):
+        hin = facility.contract(facility.DOT,
+                                jnp.concatenate([h, emb0], axis=-1),
+                                sp["in_proj"])
+        hn = L.apply_norm(sp["attn_norm"], hin, cfg)
+        a, kv_out = L.apply_attention(sp["attn"], hn, cfg, cos_sin=cos_sin,
+                                      kv=kv, q_offset=q_offset,
+                                      kv_positions=kv_positions, valid=valid)
+        hin = hin + a
+    with jax.named_scope("block.mlp"):
+        m = L.apply_mlp(sp["mlp"], L.apply_norm(sp["mlp_norm"], hin, cfg),
+                        cfg)
+        return _residual_shard(h + hin + m)
 
 
 # ======================================================================
@@ -490,12 +497,13 @@ def _run_hybrid(params, h, emb0, cfg, cos_sin, collect_cache, caches):
 
 def loss_fn(params, batch, cfg):
     logits, aux, _ = forward(params, batch, cfg)
-    labels = batch["labels"]
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    mask = (labels >= 0).astype(jnp.float32)
-    loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
-    return loss + aux, {"nll": loss, "aux": aux}
+    with jax.named_scope("loss"):
+        labels = batch["labels"]
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        mask = (labels >= 0).astype(jnp.float32)
+        loss = (nll * mask).sum() / jnp.clip(mask.sum(), 1.0)
+        return loss + aux, {"nll": loss, "aux": aux}
 
 
 # ======================================================================
@@ -590,6 +598,28 @@ def _decode_attn_inputs(cache, cfg, cur):
     return idx, valid
 
 
+def _ring_insert(lp, hh, k_c, v_c, slot, cos_sin, cfg):
+    """Project the new token's key and value and insert them into the
+    layer's ring cache at ``slot``."""
+    from repro.core import facility
+    b = hh.shape[0]
+    with jax.named_scope("block.attn"):
+        hn = L.apply_norm(lp["attn_norm"], hh, cfg)
+        with jax.named_scope("weights.cast"):
+            wk = lp["attn"]["wk"].astype(hn.dtype)
+        knew = facility.contract(facility.DOT, hn, wk).reshape(
+            b, 1, cfg.num_kv_heads, cfg.head_dim)
+        with jax.named_scope("weights.cast"):
+            wv = lp["attn"]["wv"].astype(hn.dtype)
+        vnew = facility.contract(facility.DOT, hn, wv).reshape(
+            b, 1, cfg.num_kv_heads, cfg.head_dim)
+        knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
+        with jax.named_scope("kv.write"):
+            k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew, slot, 1)
+            v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew, slot, 1)
+    return k_c, v_c
+
+
 def decode_step(params, cache, tokens, cfg):
     """One token for every sequence in the batch.  tokens (B, 1)."""
     kind = _main_kind(cfg)
@@ -616,18 +646,7 @@ def decode_step(params, cache, tokens, cfg):
             def body(carry, xs):
                 hh = carry
                 lp, k_c, v_c = xs
-                hn = L.apply_norm(lp["attn_norm"], hh, cfg)
-                # project new kv, insert into ring
-                from repro.core import facility
-                knew = facility.contract(
-                    facility.DOT, hn, lp["attn"]["wk"].astype(hn.dtype)
-                    ).reshape(b, 1, cfg.num_kv_heads, cfg.head_dim)
-                vnew = facility.contract(
-                    facility.DOT, hn, lp["attn"]["wv"].astype(hn.dtype)
-                    ).reshape(b, 1, cfg.num_kv_heads, cfg.head_dim)
-                knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
-                k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew, slot, 1)
-                v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew, slot, 1)
+                k_c, v_c = _ring_insert(lp, hh, k_c, v_c, slot, cos_sin, cfg)
                 hh, aux, _, _ = _apply_dense_block(
                     lp, hh, cfg, cos_sin=cos_sin, is_moe=is_moe,
                     kv=(k_c, v_c), window=window, q_offset=cur,
@@ -645,17 +664,7 @@ def decode_step(params, cache, tokens, cfg):
             def body_cross(carry, xs):
                 hh = carry
                 lp, k_c, v_c, ck, cv = xs
-                hn = L.apply_norm(lp["attn_norm"], hh, cfg)
-                from repro.core import facility
-                knew = facility.contract(
-                    facility.DOT, hn, lp["attn"]["wk"].astype(hn.dtype)
-                    ).reshape(b, 1, cfg.num_kv_heads, cfg.head_dim)
-                vnew = facility.contract(
-                    facility.DOT, hn, lp["attn"]["wv"].astype(hn.dtype)
-                    ).reshape(b, 1, cfg.num_kv_heads, cfg.head_dim)
-                knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
-                k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew, slot, 1)
-                v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew, slot, 1)
+                k_c, v_c = _ring_insert(lp, hh, k_c, v_c, slot, cos_sin, cfg)
                 # self attention
                 hh2, _, _, _ = _apply_dense_block(
                     lp, hh, cfg, cos_sin=cos_sin, is_moe=False,
@@ -668,10 +677,11 @@ def decode_step(params, cache, tokens, cfg):
                 lp, k_c, v_c, ck, cv = xs
                 hh, (k_c, v_c) = body_cross(carry, (lp, k_c, v_c, ck, cv))
                 # cross attention with cached encoder kv
-                hn = L.apply_norm(lp["cross_norm"], hh, cfg)
-                ca, _ = L.apply_attention(lp["cross"], hn, cfg, causal=False,
-                                          kv=(ck, cv))
-                hh = hh + ca
+                with jax.named_scope("block.attn"):
+                    hn = L.apply_norm(lp["cross_norm"], hh, cfg)
+                    ca, _ = L.apply_attention(lp["cross"], hn, cfg,
+                                              causal=False, kv=(ck, cv))
+                    hh = hh + ca
                 return hh, (k_c, v_c)
             h, (k, v) = layer_scan(body_full, h, (params["layers"], cache["k"], cache["v"],
                                cache["cross_k"], cache["cross_v"]))
@@ -717,26 +727,33 @@ def decode_step(params, cache, tokens, cfg):
             # shared attention with its ring cache
             sp = params["shared_attn"]
             from repro.core import facility
-            hin = facility.contract(facility.DOT,
-                                    jnp.concatenate([h, emb0], axis=-1),
-                                    sp["in_proj"])
-            hn = L.apply_norm(sp["attn_norm"], hin, cfg)
-            knew = facility.contract(
-                facility.DOT, hn, sp["attn"]["wk"]).reshape(
-                b, 1, cfg.num_kv_heads, cfg.head_dim)
-            vnew = facility.contract(
-                facility.DOT, hn, sp["attn"]["wv"]).reshape(
-                b, 1, cfg.num_kv_heads, cfg.head_dim)
-            knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
-            k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew, slot, 1)
-            v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew, slot, 1)
-            a, _ = L.apply_attention(sp["attn"], hn, cfg, cos_sin=cos_sin,
-                                     kv=(k_c, v_c), q_offset=cur,
-                                     kv_positions=kv_positions, valid=valid)
-            hin = hin + a
-            m = L.apply_mlp(sp["mlp"], L.apply_norm(sp["mlp_norm"], hin, cfg),
-                            cfg)
-            h = h + hin + m
+            with jax.named_scope("block.attn"):
+                hin = facility.contract(facility.DOT,
+                                        jnp.concatenate([h, emb0], axis=-1),
+                                        sp["in_proj"])
+                hn = L.apply_norm(sp["attn_norm"], hin, cfg)
+                knew = facility.contract(
+                    facility.DOT, hn, sp["attn"]["wk"]).reshape(
+                    b, 1, cfg.num_kv_heads, cfg.head_dim)
+                vnew = facility.contract(
+                    facility.DOT, hn, sp["attn"]["wv"]).reshape(
+                    b, 1, cfg.num_kv_heads, cfg.head_dim)
+                knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
+                with jax.named_scope("kv.write"):
+                    k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew,
+                                                              slot, 1)
+                    v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew,
+                                                              slot, 1)
+                a, _ = L.apply_attention(sp["attn"], hn, cfg,
+                                         cos_sin=cos_sin, kv=(k_c, v_c),
+                                         q_offset=cur,
+                                         kv_positions=kv_positions,
+                                         valid=valid)
+                hin = hin + a
+            with jax.named_scope("block.mlp"):
+                m = L.apply_mlp(sp["mlp"],
+                                L.apply_norm(sp["mlp_norm"], hin, cfg), cfg)
+                h = h + hin + m
             start += size
         new_cache["ssm"] = jnp.concatenate(ssm_all, 0)
         new_cache["conv"] = jnp.concatenate(conv_all, 0)

@@ -40,6 +40,25 @@ from repro.checkpoint.checkpoint import Checkpointer
 from repro.runtime import faults as _faults
 
 
+# The trainer loop's host spans, in the profiler's trace when one runs: a
+# step (``repro.trainer.step``, the profiler's step marker) holds the feed's
+# ``batch``, the step function's ``dispatch``, the ``wait`` for its loss, the
+# ``log`` of that loss (``on_step`` included) and, every ``ckpt_every``
+# steps, the ``checkpoint`` call.  The end-of-run save is a ``checkpoint``
+# span of its own.  The last step span holds only the ``batch`` fetch that
+# found the feed's end.
+SPAN_PREFIX = "repro.trainer."
+
+
+def _span(name: str):
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+
+
+def _span_step(step: int):
+    return jax.profiler.StepTraceAnnotation(SPAN_PREFIX + "step",
+                                            step_num=step)
+
+
 class SimulatedFailure(_faults.InjectedFault):
     """A mid-step node death.  Subclasses the registry's InjectedFault so
     one ``except`` in the restart loop covers both the trainer's own
@@ -133,45 +152,60 @@ class ElasticTrainer:
                 step_fn = self.make_step()
                 times: list[float] = []
                 slow = 0
-                for step, batch in self.batches(start):
-                    if step >= total_steps:
-                        break
-                    t0 = time.time()
-                    fault = self.faults.fire(_faults.TRAIN_STEP, step=step)
-                    if fault is not None:
-                        if fault.kind == _faults.RAISE:
-                            raise SimulatedFailure(
-                                f"injected at step {step}")
-                        if fault.kind == _faults.LATENCY:
-                            # inside the timed window: an injected
-                            # straggler the watchdog must catch
-                            time.sleep(fault.latency_s)
-                    state, metrics = step_fn(state, batch)
-                    jax.block_until_ready(metrics["loss"])
-                    dt = time.time() - t0
-                    # ---- straggler watchdog ----
-                    if len(times) >= 4:
-                        med = statistics.median(
-                            times[-self.cfg.straggler_window:])
-                        if dt > self.cfg.straggler_factor * med:
-                            slow += 1
-                            if slow >= self.cfg.straggler_patience:
-                                self.straggler_events.append(step)
+                feed = iter(self.batches(start))
+                step = start
+                while True:
+                    with _span_step(step):
+                        with _span("batch"):
+                            item = next(feed, None)
+                        if item is None or item[0] >= total_steps:
+                            break
+                        step, batch = item
+                        t0 = time.perf_counter()
+                        fault = self.faults.fire(_faults.TRAIN_STEP,
+                                                 step=step)
+                        if fault is not None:
+                            if fault.kind == _faults.RAISE:
+                                raise SimulatedFailure(
+                                    f"injected at step {step}")
+                            if fault.kind == _faults.LATENCY:
+                                # inside the timed window: an injected
+                                # straggler the watchdog must catch
+                                time.sleep(fault.latency_s)
+                        with _span("dispatch"):
+                            state, metrics = step_fn(state, batch)
+                        with _span("wait"):
+                            jax.block_until_ready(metrics["loss"])
+                        dt = time.perf_counter() - t0
+                        # ---- straggler watchdog ----
+                        if len(times) >= 4:
+                            med = statistics.median(
+                                times[-self.cfg.straggler_window:])
+                            if dt > self.cfg.straggler_factor * med:
+                                slow += 1
+                                if slow >= self.cfg.straggler_patience:
+                                    self.straggler_events.append(step)
+                                    slow = 0
+                                    if self.cfg.raise_on_straggler:
+                                        raise StragglerDetected(step, dt,
+                                                                med)
+                            else:
                                 slow = 0
-                                if self.cfg.raise_on_straggler:
-                                    raise StragglerDetected(step, dt, med)
-                        else:
-                            slow = 0
-                    times.append(dt)
-                    metrics_log.append(
-                        {"step": step,
-                         "loss": float(metrics["loss"])})
-                    if self.on_step is not None:
-                        self.on_step(step, metrics_log[-1]["loss"], dt)
-                    if (step + 1) % self.cfg.ckpt_every == 0:
-                        self.ckpt.save_async(step + 1, state)
-                self.ckpt.wait()
-                self.ckpt.save(total_steps, state)
+                        times.append(dt)
+                        with _span("log"):
+                            metrics_log.append(
+                                {"step": step,
+                                 "loss": float(metrics["loss"])})
+                            if self.on_step is not None:
+                                self.on_step(step, metrics_log[-1]["loss"],
+                                             dt)
+                        if (step + 1) % self.cfg.ckpt_every == 0:
+                            with _span("checkpoint"):
+                                self.ckpt.save_async(step + 1, state)
+                        step += 1
+                with _span("checkpoint"):
+                    self.ckpt.wait()
+                    self.ckpt.save(total_steps, state)
                 return {"state": state, "metrics": metrics_log,
                         "restarts": self.restarts,
                         "stragglers": self.straggler_events}

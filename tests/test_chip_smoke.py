@@ -104,15 +104,19 @@ def test_compile_cache_helper(monkeypatch):
     calls = []
     monkeypatch.setattr(jax.config, "update",
                         lambda k, v: calls.append((k, v)))
+    # the cache is keyed on the programs' metadata (their op names) in
+    # either case; a directory is set only where the environment names none
+    keyed = ("jax_compilation_cache_include_metadata_in_key", True)
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
     assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
-    assert calls == []
+    assert calls == [keyed]
 
+    calls.clear()
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     first = compile_cache.enable_compile_cache()
     second = compile_cache.enable_compile_cache()
     assert first == second == str(ROOT / ".jax_cache")
-    assert calls == [("jax_compilation_cache_dir", first)] * 2
+    assert calls == [keyed, ("jax_compilation_cache_dir", first)] * 2
 
 
 @pytest.mark.parametrize("b,sq,sk,h,kvh,d,causal,window,valid", [
