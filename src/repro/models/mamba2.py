@@ -175,9 +175,56 @@ def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
     return y
 
 
-def apply_mamba2(p, x, cfg, state=None):
+def _write_conv(conv_all, conv_state, layer):
+    return jax.lax.dynamic_update_index_in_dim(
+        conv_all, conv_state.astype(conv_all.dtype), layer, 0)
+
+
+def _update_state(ssm_all, sstate, dA, upd, layer):
+    """Layer ``layer``'s new SSM state ``sstate * exp(dt A) + upd`` written
+    into the stacked ``ssm_all``, and read back from it for the read-out:
+    reading the new state from the old slice would keep that slice live
+    past the write and force XLA to copy the buffer."""
+    ssm_all = jax.lax.dynamic_update_index_in_dim(
+        ssm_all, sstate * dA[..., None, None] + upd, layer, 0)
+    return ssm_all, jax.lax.dynamic_index_in_dim(ssm_all, layer,
+                                                 keepdims=False)
+
+
+# Run eagerly (``model.eager_layers()``), op by op, an update would allocate
+# and write a whole new stacked buffer; instead each runs as one program
+# that donates the buffer, the eager step's own copy (``own_decode_state``).
+_DONATED = {f: jax.jit(f, donate_argnums=0)
+            for f in (_write_conv, _update_state)}
+
+
+def _in_place(fn, buf, *args):
+    """``fn(buf, *args)``, which updates the stacked decode buffer ``buf``:
+    traced, XLA updates the carried buffer in place; eagerly, ``fn`` runs
+    as a program that donates it."""
+    if isinstance(buf, jax.core.Tracer):
+        return fn(buf, *args)
+    return _DONATED[fn](buf, *args)
+
+
+def own_decode_state(cache):
+    """The stacked ``ssm`` and ``conv`` decode buffers of ``cache``, for the
+    blocks to update in place.  Run eagerly, each block's writes donate the
+    buffer they are given (``_in_place``), so the step first takes one copy
+    of its own and leaves the caller's cache intact; traced, nothing is
+    copied."""
+    ssm, conv = cache["ssm"], cache["conv"]
+    if not isinstance(ssm, jax.core.Tracer):
+        ssm, conv = jnp.copy(ssm), jnp.copy(conv)
+    return ssm, conv
+
+
+def apply_mamba2(p, x, cfg, state=None, layer=None):
     """Full block. Training/prefill: state=None, seq scanned chunked.
-    Decode: x (B,1,d) with state dict {'ssm','conv'} -> (out, new_state)."""
+    Decode: x (B,1,d), ``state`` the whole stacked decode state
+    {'ssm': (L,B,h,n,p), 'conv': (L,B,W-1,C)} and ``layer`` this block's
+    index into it -> (out, state with the layer's slices updated in place).
+    """
     b, l, d = x.shape
     d_in, nheads, conv_dim = dims(cfg)
     n = cfg.ssm_state
@@ -201,25 +248,36 @@ def apply_mamba2(p, x, cfg, state=None):
                      "conv": jnp.pad(xbc_raw, ((0, 0), (w - 1, 0), (0, 0))
                                      )[:, -(w - 1):, :]}
     else:
-        xbc, conv_state = _causal_conv(xbc, p["conv_w"], p["conv_b"],
-                                       conv_state=state["conv"])
+        # The stacked buffers are read and written one layer slice at a
+        # time, so a donated cache is updated in place: no whole-state
+        # stacking or copy per step.
+        conv_all = state["conv"]
+        xbc, conv_state = _causal_conv(
+            xbc, p["conv_w"], p["conv_b"],
+            conv_state=jax.lax.dynamic_index_in_dim(conv_all, layer,
+                                                    keepdims=False))
+        with jax.named_scope("ssm.conv"):
+            conv_all = _in_place(_write_conv, conv_all, conv_state, layer)
         xs, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
         xh = xs.reshape(b, l, nheads, cfg.ssm_headdim)
         # single-token recurrent update: s <- exp(dt A) s + dt B x
         dA = jnp.exp(dt[:, 0] * A)                        # (b,h)
-        sstate = state["ssm"]                             # (b,h,n,p)
+        ssm_all = state["ssm"]                            # (L,b,h,n,p)
         with jax.named_scope("ssm.state"):
+            sstate = jax.lax.dynamic_index_in_dim(ssm_all, layer,
+                                                  keepdims=False)
             upd = facility.contract(
                 "bn,bhp->bhnp", B[:, 0],
                 (xh[:, 0] * dt[:, 0, :, None]).astype(x.dtype),
                 plan=Plan(out_dtype=jnp.float32))
-            sstate = sstate * dA[..., None, None] + upd
+            ssm_all, sstate = _in_place(_update_state, ssm_all, sstate, dA,
+                                        upd, layer)
             y = facility.contract("bn,bhnp->bhp", C[:, 0],
                                   sstate.astype(x.dtype))
         y = (y.astype(jnp.float32)
              + xh[:, 0].astype(jnp.float32) * p["D"][:, None])
         y = y[:, None].astype(x.dtype)
-        new_state = {"ssm": sstate, "conv": conv_state}
+        new_state = {"ssm": ssm_all, "conv": conv_all}
 
     y = y.reshape(b, l, d_in)
     # gated RMSNorm (mamba2 block output norm).  Its mean runs over all of
@@ -233,11 +291,3 @@ def apply_mamba2(p, x, cfg, state=None):
          * p["norm_scale"]).astype(x.dtype)
     return facility.contract(DOT, g, p["out_proj"]), new_state
 
-
-def init_decode_state(cfg, batch, dtype=jnp.float32):
-    d_in, nheads, conv_dim = dims(cfg)
-    return {
-        "ssm": jnp.zeros((batch, nheads, cfg.ssm_state, cfg.ssm_headdim),
-                         jnp.float32),
-        "conv": jnp.zeros((batch, cfg.ssm_conv_width - 1, conv_dim), dtype),
-    }

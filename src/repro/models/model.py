@@ -278,11 +278,26 @@ def _apply_dense_block(bp, h, cfg, *, cos_sin, is_moe, causal=None,
     return h, aux, kv_out, cross_kv
 
 
-def _apply_ssm_block(bp, h, cfg, state=None):
+def _apply_ssm_block(bp, h, cfg, state=None, layer=None):
     with jax.named_scope("block.ssm"):
         hn = L.apply_norm(bp["norm"], h, cfg)
-        out, new_state = M2.apply_mamba2(bp["mamba"], hn, cfg, state=state)
+        out, new_state = M2.apply_mamba2(bp["mamba"], hn, cfg, state=state,
+                                         layer=layer)
         return _residual_shard(h + out), new_state
+
+
+def _ssm_decode_layers(layers, h, cfg, ssm, conv, start=0):
+    """Decode through a stack of SSM blocks, the state carried whole:
+    block ``i`` updates slice ``start + i`` of the stacked ``ssm`` and
+    ``conv`` buffers in place (mamba2.apply_mamba2)."""
+    def body(carry, lp):
+        hh, i, ssm, conv = carry
+        hh, st = _apply_ssm_block(lp, hh, cfg,
+                                  state={"ssm": ssm, "conv": conv}, layer=i)
+        return (hh, i + 1, st["ssm"], st["conv"]), None
+    (h, _, ssm, conv), _ = layer_scan(
+        body, (h, jnp.asarray(start, jnp.int32), ssm, conv), layers)
+    return h, ssm, conv
 
 
 def _apply_shared_attn(sp, h, emb0, cfg, *, cos_sin, kv=None, q_offset=0,
@@ -691,13 +706,8 @@ def decode_step(params, cache, tokens, cfg):
         new_cache["pos"] = kv_positions[0]
 
     elif kind == "ssm":
-        def body(carry, xs):
-            lp, sstate, cstate = xs
-            hh, st = _apply_ssm_block(lp, carry, cfg,
-                                      state={"ssm": sstate, "conv": cstate})
-            return hh, (st["ssm"], st["conv"])
-        h, (ssm, conv) = layer_scan(body, h, (params["layers"], cache["ssm"], cache["conv"]))
-        new_cache["ssm"], new_cache["conv"] = ssm, conv
+        h, new_cache["ssm"], new_cache["conv"] = _ssm_decode_layers(
+            params["layers"], h, cfg, *M2.own_decode_state(cache))
 
     elif kind == "hybrid":
         clen = cache["pos"].shape[0]
@@ -706,24 +716,15 @@ def decode_step(params, cache, tokens, cfg):
         valid = kv_positions >= 0
         every = cfg.shared_attn_every
         n = cfg.num_layers
-        ssm_all, conv_all = [], []
+        ssm, conv = M2.own_decode_state(cache)
         start = 0
         k_c, v_c = cache["k"], cache["v"]
         while start < n:
             size = min(every, n - start)
             group = jax.tree.map(lambda a: a[start:start + size],
                                  params["layers"])
-            sgrp = cache["ssm"][start:start + size]
-            cgrp = cache["conv"][start:start + size]
-
-            def body(carry, xs):
-                lp, sstate, cstate = xs
-                hh, st = _apply_ssm_block(
-                    lp, carry, cfg, state={"ssm": sstate, "conv": cstate})
-                return hh, (st["ssm"], st["conv"])
-            h, (ssm_g, conv_g) = layer_scan(body, h, (group, sgrp, cgrp))
-            ssm_all.append(ssm_g)
-            conv_all.append(conv_g)
+            h, ssm, conv = _ssm_decode_layers(group, h, cfg, ssm, conv,
+                                              start)
             # shared attention with its ring cache
             sp = params["shared_attn"]
             from repro.core import facility
@@ -755,8 +756,7 @@ def decode_step(params, cache, tokens, cfg):
                                 L.apply_norm(sp["mlp_norm"], hin, cfg), cfg)
                 h = h + hin + m
             start += size
-        new_cache["ssm"] = jnp.concatenate(ssm_all, 0)
-        new_cache["conv"] = jnp.concatenate(conv_all, 0)
+        new_cache["ssm"], new_cache["conv"] = ssm, conv
         new_cache["k"], new_cache["v"] = k_c, v_c
         new_cache["pos"] = kv_positions[0]
 
