@@ -10,15 +10,22 @@ Each fault replaces one program function for the extent of a ``with``:
 ``half_batch``   the loss is taken over the first half of the batch only
                  (training), or the second half of a generated batch
                  repeats the first half's tokens (generation);
-``stale_state``  the training step hands back the state it was given.
+``stale_state``  the training step hands back the state it was given;
+``exchange``     on a mesh, each chip keeps only its own block of a
+                 sharded gemm's activation rows and takes it for every
+                 block: the gather between chips before the gemm is
+                 left out.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 
 import jax.numpy as jnp
+from jax import lax
 
+from repro.core import lowering, packing
 from repro.models import model as M
 from repro.train import steps as S
 
@@ -86,12 +93,38 @@ def _stale(orig):
     return make_train_step
 
 
+def _own_rows_only(orig):
+    def _shard_wrap(sp):
+        wrap = orig(sp)
+        parts = dict(sp.mesh.shape).get("model", 1)
+
+        def own_rows(fn):
+            def run(op):
+                p = op.parsed
+                if p is None or parts == 1 or packing.is_packed(op.x):
+                    return fn(op)
+                rows = [i for i, d in enumerate(p.x_labels)
+                        if d in p.x_free and op.x.shape[i] % parts == 0]
+                if not rows:
+                    return fn(op)
+                axis = max(rows, key=lambda i: op.x.shape[i])
+                size = op.x.shape[axis] // parts
+                mine = lax.dynamic_slice_in_dim(
+                    op.x, lax.axis_index("model") * size, size, axis)
+                return fn(dataclasses.replace(
+                    op, x=jnp.concatenate([mine] * parts, axis)))
+            return wrap(run)
+        return own_rows
+    return _shard_wrap
+
+
 def plant(name: str, kind: str):
     """A context that plants fault ``name`` under a job of ``kind``."""
     table = {
         ("token", "generate"): (S, "make_serve_step", _token),
         ("half_batch", "generate"): (S, "make_serve_step", _half_rows),
         ("answer", "score"): (S, "make_prefill_step", _answer),
+        ("exchange", "score"): (lowering, "_shard_wrap", _own_rows_only),
         ("half_batch", "train"): (M, "loss_fn", _half_loss),
         ("stale_state", "train"): (S, "make_train_step", _stale),
     }
@@ -100,3 +133,10 @@ def plant(name: str, kind: str):
 
 FAULTS = {"generate": ("token", "half_batch"), "score": ("answer",),
           "train": ("stale_state", "half_batch")}
+
+
+def of(cell) -> tuple:
+    """The faults a cell (``spec.Cell``) can have: its job's, and on more
+    than one chip the exchange between them."""
+    return FAULTS[cell.traffic["job"]] + (
+        ("exchange",) if cell.workload["chips"] > 1 else ())

@@ -9,6 +9,7 @@ memory is read, the program's state is freed, and the check runs.
 
 from __future__ import annotations
 
+import collections
 import gc
 import json
 import statistics
@@ -19,7 +20,11 @@ import jax
 
 from benchlib import device, jobs, readers, spec, trace
 
+from repro.launch import compile_cache
+
 TRACE_DIR = spec.ROOT / ".bench_trace"
+COMPILE_KEYS = ("backend_compiles", "backend_compile_s", "cache_hits",
+                "cache_misses", "cache_retrieval_s")
 
 
 def prepare_process():
@@ -31,10 +36,9 @@ def prepare_process():
     import os
 
     from repro.core import autotune
-    from repro.launch.compile_cache import enable_compile_cache
     os.environ[autotune.DEFAULT_CACHE_ENV] = str(spec.ROOT /
                                                  ".autotune.json")
-    enable_compile_cache()
+    compile_cache.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
@@ -51,17 +55,23 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     cell = spec.load_cell(workload, rehearse=rehearse)
     chips = cell.workload["chips"]
     if rehearse:
-        devs = jax.devices()[:1]
+        devs = jax.devices()[:chips]
+        if len(devs) < chips:
+            raise RuntimeError(f"the rehearsal of {workload} needs {chips} "
+                               f"devices, JAX sees {len(devs)}")
         peaks = None
     else:
         devs = device.find_chips(chips)
         peaks = device.peaks(devs[0].device_kind)
         prepare_process()
-    job = jobs.make_job(cell, seed, rehearse)
+    compile_cache.count_compiles()
+    job = jobs.make_job(cell, seed, rehearse, devs)
     job.setup()
     tracer = trace.Tracer(job.trace_units if traced else 0, TRACE_DIR)
     info = job.window(seconds, tracer)
     tracer.finish()
+    compiled = collections.Counter(compile_cache.COMPILE_COUNTS)
+    compiled.subtract(info["compiles_at_open"])
     setup_s = info["opened"] - t_start
 
     reduced = tracer.reduce() if traced else None
@@ -85,7 +95,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     if traced:
         ctx = readers.Context(kind=job.kind, trace=reduced, peaks=peaks,
                               unit_work=info["unit_work"],
-                              units=tracer.units_done)
+                              units=tracer.units_done, chips=len(devs))
         metrics = {}
         for m in cell.per_layer:
             value = (spec.metric_reader(m["name"])(ctx)
@@ -103,9 +113,19 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool, *,
     out["checks"] = {k: {"value": checks[k], "limit": limits[k]}
                      for k in sorted(limits)}
     out["units"] = info["ends"]
+    out["compiles"] = {k: compiled[k] for k in COMPILE_KEYS}
     out["readings"] = dict(program=program, **({"control": checks}
                                                 if control else {}))
     return out
+
+
+def compiles_line(compiled: dict) -> str:
+    """The program's compile counters over the measured window, as
+    ``launch/compile_cache.COMPILE_COUNTS`` counts them: every program
+    handed to the backend (compiled, or loaded from the persistent
+    cache) and its seconds.  A warm window reads 0 throughout."""
+    return "window compiles: " + ", ".join(
+        f"{k} {compiled[k]:g}" for k in COMPILE_KEYS)
 
 
 def main(args, t_start: float) -> int:
@@ -117,6 +137,7 @@ def main(args, t_start: float) -> int:
         print(f"bench: {e}; nothing measured", file=sys.stderr)
         return 2
     ends = out.pop("units")
+    compiled = out.pop("compiles")
     del out["readings"]
     took = [b - a for a, b in zip(ends, ends[1:])]
     if took:
@@ -124,6 +145,7 @@ def main(args, t_start: float) -> int:
         print(f"window: {len(took)} units, median "
               f"{statistics.median(took):.4f} s, slowest {took[slow]:.4f} s "
               f"(unit {slow})", file=sys.stderr)
+    print(compiles_line(compiled), file=sys.stderr)
     for name, c in out["checks"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

@@ -14,29 +14,37 @@ Three kinds of job, named by the traffic file's ``job``:
 ``train``     steps of ``batch`` x ``seq`` tokens through the trainer.
 
 Every job makes its weights on the device from the seed, in one jitted
-call, and its inputs from the seed.  ``setup`` compiles and warms up;
-``window`` measures; ``check`` compares what the window produced with the
-configuration's plain reference after the program's state is freed.
+call, and its inputs from the seed.  A configuration with a ``mesh``
+(a score job) makes each chip's shard of the weights, as the program lays
+them out, and runs the program across the mesh under its own sharding
+rules.  ``setup`` compiles and warms up; ``window`` measures; ``check``
+compares what the window produced with the configuration's plain
+reference after the program's state is freed.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import mesh_utils
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from benchlib import compare, counts, spec, trace
 from references import common
 
 from repro.configs.base import ArchConfig, reduced as reduce_arch
 from repro.core import facility
-from repro.launch import serve, train
+from repro.launch import compile_cache, serve, train
+from repro.launch import specs as SP
 from repro.models import model as M
 from repro.optim import adamw
+from repro.parallel import api as par
 from repro.runtime.elastic import ElasticConfig, ElasticTrainer
 from repro.train import steps as S
 
@@ -48,23 +56,75 @@ class Model:
     cfg: dict           # the configuration as run (reference side)
     arch: ArchConfig    # the same, as the program takes it
     ref: object         # the plain reference module
+    rules: par.ShardingRules | None = None   # the mesh's, where one is
+
+    @property
+    def param_shardings(self):
+        """Each weight's ``NamedSharding`` on the mesh: the program's own
+        ``param_spec`` over ``param_axes``, as its launchers lay them."""
+        abstract = jax.eval_shape(lambda: M.init_params(self.arch,
+                                                        jax.random.key(0)))
+        tree = par.tree_param_specs(abstract, M.param_axes(self.arch),
+                                    self.rules)
+        return jax.tree.map(lambda p: NamedSharding(self.rules.mesh, p),
+                            tree, is_leaf=lambda x: isinstance(x, P))
+
+    def jit(self, step, batch: dict):
+        """``jax.jit`` of a ``step(params, batch)``; on a mesh, with the
+        weights' shardings and ``batch`` (arrays of the shapes it will
+        be given) laid out by the program's rules."""
+        if self.rules is None:
+            return jax.jit(step)
+        axes = SP.batch_axes(self.arch, for_train=False)
+        return jax.jit(step, in_shardings=(self.param_shardings, {
+            k: NamedSharding(self.rules.mesh, par.activation_spec(
+                v.shape, axes[k], self.rules)) for k, v in batch.items()}))
+
+    @contextlib.contextmanager
+    def running(self):
+        """The program's facility config and, on a mesh, its sharding
+        rules and the mesh: every contraction traced here is placed by
+        the program's own shard planner."""
+        with facility.configure(kernel_config()):
+            if self.rules is None:
+                yield
+            else:
+                with par.use_rules(self.rules), self.rules.mesh:
+                    yield
 
 
-def make_model(config: dict, rehearse: bool) -> Model:
+def make_mesh(config: dict, devs) -> par.ShardingRules | None:
+    """The program's default rules over a mesh of ``devs`` shaped as the
+    configuration's ``mesh`` (axis name -> size, in order); ``None`` for
+    a configuration without one, or without devices (its sizes only)."""
+    shape = config.get("mesh")
+    if not shape or devs is None:
+        return None
+    grid = mesh_utils.create_device_mesh(tuple(shape.values()),
+                                         devices=list(devs))
+    return par.default_rules(Mesh(grid, tuple(shape)))
+
+
+def make_model(config: dict, rehearse: bool, devs=None) -> Model:
     names = {f.name for f in dataclasses.fields(ArchConfig)}
     arch = ArchConfig(**{k: v for k, v in config.items() if k in names})
     if rehearse:
         arch = reduce_arch(arch)
     return Model(cfg=dataclasses.asdict(arch), arch=arch,
-                 ref=spec.reference(config["reference"]))
+                 ref=spec.reference(config["reference"]),
+                 rules=make_mesh(config, devs))
 
 
-def make_job(cell, seed: int, rehearse: bool = False):
-    """The job of a cell (``spec.Cell``): its traffic file's parameters,
-    with its ``rehearse`` block over them in a rehearsal."""
+def make_job(cell, seed: int, rehearse: bool = False, devs=None):
+    """The job of a cell (``spec.Cell``) on the devices ``devs``: its
+    traffic file's parameters, with its ``rehearse`` block over them in a
+    rehearsal."""
     p = dict(cell.traffic, **(cell.traffic.get("rehearse", {}) if rehearse
                               else {}))
-    return JOBS[p["job"]](make_model(cell.config, rehearse), p, seed)
+    model = make_model(cell.config, rehearse, devs)
+    if model.rules is not None and p["job"] != "score":
+        raise ValueError(f"{cell.name}: a mesh runs score jobs only")
+    return JOBS[p["job"]](model, p, seed)
 
 
 def kernel_config() -> facility.FacilityConfig:
@@ -79,8 +139,11 @@ def kernel_config() -> facility.FacilityConfig:
 
 def make_weights(model: Model, seed: int):
     """The weights, made on the device from the seed in one jitted call,
-    in the program's layout, which is checked against its own init."""
-    w = jax.jit(lambda k: model.ref.init_weights(model.cfg, k))(
+    in the program's layout, which is checked against its own init.  On a
+    mesh each chip makes only its own shard of each weight."""
+    placed = ({} if model.rules is None else
+              {"out_shardings": model.param_shardings})
+    w = jax.jit(lambda k: model.ref.init_weights(model.cfg, k), **placed)(
         common.key_from_seed(seed))
     want = jax.eval_shape(lambda: M.init_params(model.arch,
                                                 jax.random.key(0)))
@@ -103,6 +166,13 @@ def rng_for(seed: int, *stream: int) -> np.random.Generator:
     """A generator for one stream of the seed; a stream's numbers may be
     -1 (the warm-up's inputs), never lower."""
     return np.random.default_rng([int(seed), *(s + 1 for s in stream)])
+
+
+def window_opens() -> tuple[float, collections.Counter]:
+    """The measured window's opening: the clock, and the program's compile
+    counters, which the harness reads again when the window closes."""
+    counts_now = collections.Counter(compile_cache.COMPILE_COUNTS)
+    return time.perf_counter(), counts_now
 
 
 def _sample(seed: int, population: int, k: int) -> list[int]:
@@ -166,7 +236,8 @@ class Generate:
     def window(self, seconds: float, tracer) -> dict:
         done = 0
         with facility.configure(kernel_config()):
-            t0 = t_end = time.perf_counter()
+            t0, at_open = window_opens()
+            t_end = t0
             ends = [t0]
             while t_end - t0 < seconds:
                 tracer.before(done)
@@ -178,6 +249,7 @@ class Generate:
                 done += 1
         rows = done * self.batch
         return {"attempted": rows, "failed": 0, "opened": t0, "ends": ends,
+                "compiles_at_open": at_open,
                 "end_to_end": {"gen_tok_s": rows * self.gen_len
                                / (t_end - t0)},
                 "unit_work": counts.generate_batch(
@@ -236,17 +308,19 @@ class Score:
 
     def setup(self):
         self.weights = make_weights(self.model, self.seed)
-        self.prefill = jax.jit(S.make_prefill_step(self.model.arch))
-        with facility.configure(kernel_config()):
-            logits, _ = self.prefill(self.weights,
-                                     {"tokens": jnp.asarray(self._prompt(-1))})
+        warm = {"tokens": jnp.asarray(self._prompt(-1))}
+        self.prefill = self.model.jit(S.make_prefill_step(self.model.arch),
+                                      warm)
+        with self.model.running():
+            logits, _ = self.prefill(self.weights, warm)
             np.asarray(logits)[0]
 
     def window(self, seconds: float, tracer) -> dict:
         pending = collections.deque()
         sent = 0
-        with facility.configure(kernel_config()):
-            t0 = t_end = time.perf_counter()
+        with self.model.running():
+            t0, at_open = window_opens()
+            t_end = t0
             ends = [t0]
             while True:
                 open_ = t_end - t0 < seconds
@@ -269,7 +343,7 @@ class Score:
                 tracer.after(i)
         n = len(self.answers)
         return {"attempted": sent, "failed": sent - n, "opened": t0,
-                "ends": ends,
+                "ends": ends, "compiles_at_open": at_open,
                 "end_to_end": {"prompt_tok_s": n * self.prompt_len
                                / (t_end - t0)},
                 "unit_work": counts.score_prompt(self.model.cfg,
@@ -410,7 +484,7 @@ class Train:
                 ahead = {s: self._device_batch(s) for s in
                          range(step, step + self.feed_steps)}
                 jax.block_until_ready(ahead)
-                self.opened = time.perf_counter()
+                self.opened, self.at_open = window_opens()
                 self.deadline = self.opened + self.seconds
             if unit >= 0:
                 if time.perf_counter() >= self.deadline:
@@ -442,7 +516,7 @@ class Train:
         t = [self.opened] + self.times[self.warm_steps:]
         steps = len(t) - 1
         return {"attempted": steps, "failed": 0, "opened": self.opened,
-                "ends": t,
+                "ends": t, "compiles_at_open": self.at_open,
                 "end_to_end": {"train_tok_s": steps * self.batch * self.seq
                                / (t[-1] - t[0])},
                 "unit_work": (counts.train_step(self.model.cfg, self.batch,

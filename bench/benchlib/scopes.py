@@ -266,16 +266,14 @@ class Scoped(trace.Reduced):
     # ---------------------------------------------------------- reduce
     def by_scope(self, program: str):
         """({scope: device seconds}, executions): the ops of chip 0 inside
-        the executions of the program whose name holds ``program`` that
-        ran whole in the window, summed by innermost scope (``None``:
-        unscoped); containers are left out, as their bodies' ops count.
-        An execution the trace holds only in part (the one running when
-        the profiler stopped: a few microseconds and one op) is left out
-        too, as one whose recorded ops fill less than half of it."""
-        lo, hi = self.window
-        runs = sorted((s, e) for s, e, name in
-                      (self.modules[0] if self.modules else [])
-                      if program in name and lo <= s < e <= hi)
+        the executions of the program whose name holds ``program``
+        (:meth:`trace.Reduced.executions`), summed by innermost scope
+        (``None``: unscoped); containers are left out, as their bodies'
+        ops count.  An execution the trace holds only in part (the one
+        running when the profiler stopped: a few microseconds and one op)
+        is left out too, as one whose recorded ops fill less than half of
+        it."""
+        runs = self.executions(program)
         starts = [s for s, _ in runs]
         per_run: list = [{} for _ in runs]
         for (s, e, _, cls_name), scope in zip(

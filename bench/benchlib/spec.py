@@ -1,7 +1,8 @@
 """Finds what a cell is made of, by the names in ``BENCHMARK.json``.
 
     BENCHMARK.json                  the cells, metrics and bounds
-    bench/configs/<config>.json     a configuration's sizes, as run
+    bench/configs/<config>.json     a configuration's sizes, as run, and
+                                    its ``mesh`` where it spans chips
     bench/traffic/<traffic>.json    a traffic mix or job: its parameters
     bench/limits/<workload>.json    the limits that decide ``correct``
                                     (none yet: the cell is never correct);
@@ -20,6 +21,7 @@ import dataclasses
 import importlib
 import importlib.util
 import json
+import math
 import pathlib
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
@@ -59,6 +61,12 @@ def load_cell(workload: str, benchmark: dict | None = None, *,
                        f"have {sorted(by_name)}")
     w = by_name[workload]
     configs = {c["name"]: c for c in bm["configs"]}
+    config = _json(ROOT / configs[w["config"]]["file"])
+    mesh = config.get("mesh")
+    if mesh and math.prod(mesh.values()) != w["chips"]:
+        raise ValueError(f"{workload}: the mesh {mesh} of its configuration "
+                         f"spans {math.prod(mesh.values())} chips, the cell "
+                         f"asks for {w['chips']}")
     path = BENCH / "limits" / f"{workload}.json"
     limits = _json(path) if path.exists() else {}
     at_size = limits.pop("rehearse", {})
@@ -66,7 +74,7 @@ def load_cell(workload: str, benchmark: dict | None = None, *,
         limits.update(at_size)
     return Cell(
         workload=w,
-        config=_json(ROOT / configs[w["config"]]["file"]),
+        config=config,
         traffic=_json(BENCH / "traffic" / f"{w['traffic']}.json"),
         limits=limits,
         end_to_end=[m for m in bm["end_to_end"] if _reports(m, workload)],

@@ -7,7 +7,8 @@ the host, and every operation the device ran.  The reduction keeps:
 * the window: from the first harness span's start to the last one's end;
 * per chip, the operations that ran in it, each with its class -- a
   contraction (a Pallas gemm kernel, or an XLA fusion around a dot or a
-  convolution), attention (the flash-attention kernel), or other;
+  convolution), attention (the flash-attention kernel), an exchange
+  between chips (a collective), or other;
 * per chip, the executions of each compiled program (XLA module);
 * the harness's spans.
 
@@ -19,12 +20,14 @@ reduction is checked against a small recorded trace without a chip.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import json
 import os
 import re
 import shutil
+import statistics
 
 import jax
 
@@ -33,6 +36,11 @@ SPAN_PREFIX = "bench."
 # Operations that hold other operations (their events span their bodies'):
 # they count as busy time but are no operation of their own.
 CONTAINERS = ("while", "conditional", "call")
+
+# XLA's collectives; each also runs as an async pair, ``<op>-start`` and
+# ``<op>-done``, whose own durations are the time the chip spends on it.
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
 
 
 class Tracer:
@@ -89,12 +97,18 @@ def compact(text: str) -> str:
                                              else "")
 
 
+def _collective(op: str) -> bool:
+    return op.removesuffix("-start").removesuffix("-done") in COLLECTIVES
+
+
 def classify(name: str) -> str:
-    """'gemm', 'attention', 'container' or 'other' for one operation,
-    from its compact name: a Pallas custom call by the name the program
-    gives its implementation (``_pallas_gemm_impl``, ``_pallas_attn_impl``),
-    and XLA's own contractions as dot or convolution instructions or the
-    output fusions built around them (``kind=kOutput``)."""
+    """'gemm', 'attention', 'collective', 'container' or 'other' for one
+    operation, from its compact name: a Pallas custom call by the name the
+    program gives its implementation (``_pallas_gemm_impl``,
+    ``_pallas_attn_impl``), XLA's own contractions as dot or convolution
+    instructions or the output fusions built around them
+    (``kind=kOutput``), and a collective as its instruction or a fusion
+    named for the collective at its root (``%all-gather-fusion.3``)."""
     head, _, rest = name.partition(" = ")
     words = rest.split()
     op = words[-2] if len(words) >= 2 and words[-1].startswith("k") else (
@@ -107,9 +121,13 @@ def classify(name: str) -> str:
         if "attn" in short or "flash" in short:
             return "attention"
         return "gemm" if "gemm" in short else "other"
+    if _collective(op):
+        return "collective"
     if op in ("dot", "convolution") or (op == "fusion" and
                                         kind == "kOutput"):
         return "gemm"
+    if op == "fusion" and _collective(re.sub(r"[-.]?fusion.*", "", short)):
+        return "collective"
     return "other"
 
 
@@ -208,6 +226,38 @@ class Reduced:
         return [(e - s) * 1e-9 for s, e, name in
                 (self.modules[0] if self.modules else [])
                 if key in name and lo <= s < e <= hi]
+
+    def executions(self, key: str) -> list:
+        """[(start, end)] of the executions, on chip 0, of the programs
+        whose name holds ``key`` that ran whole inside the window.  The
+        one the profiler's stop cut short is left out: its recorded span
+        is under half the median execution's."""
+        lo, hi = self.window
+        runs = sorted((s, e) for s, e, name in
+                      (self.modules[0] if self.modules else [])
+                      if key in name and lo <= s < e <= hi)
+        if runs:
+            half = statistics.median(e - s for s, e in runs) / 2
+            runs = [(s, e) for s, e in runs if e - s >= half]
+        return runs
+
+    def class_seconds_per_run(self, key: str, cls_name: str) -> list:
+        """Device seconds of the operations of one class, on chip 0, in
+        each of :meth:`executions`; an execution whose recorded ops fill
+        less than half of it is left out, as the trace holds it only in
+        part."""
+        runs = self.executions(key)
+        starts = [s for s, _ in runs]
+        filled, per = [0] * len(runs), [0] * len(runs)
+        for s, e, _, c in (self.ops[0] if self.ops else []):
+            i = bisect.bisect_right(starts, s) - 1
+            if c == "container" or i < 0 or e > runs[i][1]:
+                continue
+            filled[i] += e - s
+            if c == cls_name:
+                per[i] += e - s
+        return [t * 1e-9 for t, f, (s, e) in zip(per, filled, runs)
+                if 2 * f >= e - s]
 
     def idle_gaps(self, chip: int = 0) -> list:
         """[(seconds, span name)] of every gap between busy intervals in

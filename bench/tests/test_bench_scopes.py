@@ -251,3 +251,112 @@ def test_recorded_train_step_and_trainer_spans():
     idle = r.window_s() - r.busy_s()
     assert 0.9 * idle < r.program_idle_s(scopes.TRAINER) <= idle
     assert 2 < scopes.trainer_idle_ms(ctx) < 6
+
+
+# What the readers gave on the recorded traces before they took the run's
+# chip count; with ``chips`` 1 they give the same, to the last bit.  The
+# gen-b64 trace holds three decode steps, read as three units of a decode
+# step's contractions; the train-2k trace one step.
+RECORDED_READINGS = {
+    "gen-b64": ("mamba2-130m.gen-b64", 3, {
+        "step_ms.gen": 16.472004000000002,
+        "mfu.gen": 0.5448701828840086,
+        "gemm_roofline.gen": 90.45837166347565,
+        "idle_share.gen": 0.012016499043676632,
+        "contract_ms.gen": 5.678405333333333,
+        "unscoped_ms.gen": 8.720148333333334}),
+    "train-2k": ("mamba2-130m.train-2k", 1, {
+        "step_ms.train": 467.831401,
+        "mfu.train": 15.657023994597608,
+        "idle_share.train": 0.9671275173515959,
+        "contract_ms.train": 198.20996699999998,
+        "unscoped_ms.train": 66.435143,
+        "trainer_idle_ms.train": 4.282924}),
+    "score-2k": ("deepseek-7b-pp4.score-2k", 3, SCORE_2K),
+}
+
+
+def _unit_work(cell):
+    cfg, t = jobs.make_model(cell.config, False).cfg, cell.traffic
+    if t["job"] == "generate":
+        return (counts.contractions(cfg, t["batch"], 1, head_rows=t["batch"]),
+                counts.Work())
+    if t["job"] == "score":
+        return counts.score_prompt(cfg, t["prompt_len"])
+    return counts.train_step(cfg, t["batch"], t["seq"]), counts.Work()
+
+
+@pytest.mark.parametrize("fixture", sorted(RECORDED_READINGS))
+def test_recorded_traces_read_as_before_on_one_chip(fixture):
+    name, units, want = RECORDED_READINGS[fixture]
+    cell = spec.load_cell(name)
+    r = scopes.Scoped.from_json(json.loads(
+        (DATA / f"{fixture}.trace.json").read_text()))
+    for _, _, op, cls in r.ops[0]:
+        assert trace.classify(op) == cls
+    ctx = readers.Context(kind=cell.traffic["job"], trace=r,
+                          peaks=device.PEAKS["TPU v5 lite"],
+                          unit_work=_unit_work(cell), units=units, chips=1)
+    got = {m["name"]: spec.metric_reader(m["name"])(ctx)
+           for m in cell.per_layer}
+    assert {n: v for n, v in got.items() if v is not None} == want
+
+
+def test_an_execution_the_profiler_cut_short_is_left_out():
+    # two prefills of 30 ms, then one the profiler's stop cut to 1 ms,
+    # which its one recorded op fills
+    r = scopes.Scoped.from_json({
+        "window": [0, 100 * MS],
+        "ops": [[[0, 20 * MS, "custom-call.1", "gemm"],
+                 [20 * MS, 30 * MS, "all-gather.2", "collective"],
+                 [30 * MS, 50 * MS, "custom-call.1", "gemm"],
+                 [50 * MS, 60 * MS, "all-gather.2", "collective"],
+                 [60 * MS, 61 * MS, "custom-call.1", "gemm"]]],
+        "modules": [[[0, 30 * MS, "jit_prefill_step(1)"],
+                     [30 * MS, 60 * MS, "jit_prefill_step(1)"],
+                     [60 * MS, 61 * MS, "jit_prefill_step(1)"]]],
+        "spans": [[0, 100 * MS, "client"]],
+        "scopes": [["contract.gemm.pallas", None, "contract.gemm.pallas",
+                    None, "contract.gemm.pallas"]],
+        "program_spans": []})
+    assert r.executions("prefill_step") == [(0, 30 * MS), (30 * MS, 60 * MS)]
+    seconds, runs = r.by_scope("prefill_step")
+    assert runs == 2
+    ctx = _ctx("score", r)
+    assert scopes.contract_ms(ctx, "score", "prefill_step") == \
+        pytest.approx(20.0)
+    assert r.class_seconds_per_run("prefill_step", "collective") == [
+        pytest.approx(0.010), pytest.approx(0.010)]
+
+
+def test_recorded_four_chip_prompt():
+    """One prompt of ``deepseek-7b-tp4.score-2k`` as four v5e chips traced
+    it (a chip run of the benchmark, cut by ``scopes.save_small``)."""
+    r = _recorded_scoped("score-2k-tp4")
+    assert len(r.ops) == 4
+    for chip in r.ops:
+        for _, _, name, cls in chip:
+            assert trace.classify(name) == cls
+    by_class = {}
+    for _, _, name, cls in r.ops[0]:
+        by_class.setdefault(cls, set()).add(name.split()[-1])
+    assert by_class["collective"] == {"all-gather", "all-reduce",
+                                      "all-to-all"}
+    assert by_class["attention"] == by_class["gemm"] == {"custom-call"}
+    cell = spec.load_cell("deepseek-7b-tp4.score-2k")
+    work = counts.score_prompt(jobs.make_model(cell.config, False).cfg, 2048)
+
+    def ctx(chips):
+        return readers.Context(kind="score", trace=r,
+                               peaks=device.PEAKS["TPU v5 lite"],
+                               unit_work=work, units=1, chips=chips)
+    got = {m["name"]: spec.metric_reader(m["name"])(ctx(4))
+           for m in cell.per_layer}
+    assert got["step_ms.score"] == pytest.approx(130.68, rel=1e-3)
+    assert got["collective_ms.score"] == pytest.approx(40.25, rel=1e-3)
+    assert got["contract_ms.score"] == pytest.approx(64.07, rel=1e-3)
+    assert 0 < got["mfu.score"] < got["gemm_roofline.score"] < 100
+    # read as one chip's, the same work would be four times the share
+    assert readers.mfu(ctx(1), "score") == pytest.approx(
+        4 * got["mfu.score"])
+    assert readers.roofline(ctx(1), "score", "gemm") > 100

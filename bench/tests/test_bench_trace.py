@@ -133,3 +133,75 @@ def test_recorded_trace_busy_idle_and_programs():
     gaps = r.idle_gaps()
     assert sum(g for g, _ in gaps) == pytest.approx(window - busy)
     assert {who for _, who in gaps} <= {"prefill", "client"}
+
+
+@pytest.mark.parametrize("name,cls", [
+    ("%all-gather-start.3 = (bf16[512,4096], bf16[2048,4096]) "
+     "all-gather-start", "collective"),
+    ("%all-gather-done.3 = bf16[2048,4096] all-gather-done", "collective"),
+    ("%all-gather.54 = bf16[1,2048,4096] all-gather", "collective"),
+    ("%all-reduce.1 = bf16[1,2048,4096] all-reduce", "collective"),
+    ("%reduce-scatter.2 = bf16[512,4096] reduce-scatter", "collective"),
+    ("%all-to-all.33 = bf16[1,2752,4,1024] all-to-all", "collective"),
+    ("%collective-permute-done.1 = bf16[8] collective-permute-done",
+     "collective"),
+    ("%all-reduce-fusion.2 = f32[2048] fusion kLoop", "collective"),
+    ("%fusion.56 = bf16[64,24,128,64] fusion kLoop", "other"),
+    ("%reduce.4 = f32[2048] reduce", "other"),
+    ("%_pallas_gemm_impl.43 = bf16[2048,4096] custom-call", "gemm"),
+    ("%fusion.504 = bf16[8,8,24,256,256] fusion kOutput", "gemm"),
+    ("%_pallas_attn_impl.5 = bf16[1,32,2048,128] custom-call", "attention"),
+])
+def test_classify_collectives(name, cls):
+    assert trace.classify(name) == cls
+
+
+def _chips(n, work_ms):
+    """A prefill of ``work_ms`` ms of gemm split over ``n`` chips: each
+    chip runs its share in ``work_ms / n`` ms, then 1 ms of collectives
+    (start 0.4, done 0.6), inside one execution; the window fits it."""
+    t = int(work_ms / n * MS)
+    chip_ops = [[0, t, "%_pallas_gemm_impl.1 = bf16[8] custom-call", "gemm"],
+                [t, t + 4 * MS // 10, "%all-gather-start.1 = bf16[8] "
+                 "all-gather-start", "collective"],
+                [t + 4 * MS // 10, t + MS, "%all-gather-done.1 = bf16[8] "
+                 "all-gather-done", "collective"]]
+    return trace.Reduced.from_json({
+        "window": [0, t + MS],
+        "ops": [list(chip_ops) for _ in range(n)],
+        "modules": [[[0, t + MS, "jit_prefill_step"]] for _ in range(n)],
+        "spans": [[0, t + MS, "prefill"]]})
+
+
+def test_shares_of_a_peak_are_per_chip():
+    peaks = {"bf16_flops": 100e12, "hbm_bytes_per_s": 1e12}
+    work = (Work(2e12, 1e9, ((2e12, 1e9, 1),)), Work())
+    one, four = _chips(1, 40), _chips(4, 160)
+    ctx1 = readers.Context(kind="score", trace=one, peaks=peaks,
+                           unit_work=work, units=1)
+    # four times the work, split four ways, in the same time a chip
+    work4 = (work[0] * 4, Work())
+    ctx4 = readers.Context(kind="score", trace=four, peaks=peaks,
+                           unit_work=work4, units=1, chips=4)
+    assert readers.mfu(ctx4, "score") == pytest.approx(
+        readers.mfu(ctx1, "score"))
+    assert readers.roofline(ctx4, "score", "gemm") == pytest.approx(
+        readers.roofline(ctx1, "score", "gemm"))
+    # 2e12 FLOP in 41 ms against 100 TFLOP/s; 20 ms least over 40 of gemm
+    assert readers.mfu(ctx1, "score") == pytest.approx(100 * 20 / 41)
+    assert readers.roofline(ctx1, "score", "gemm") == pytest.approx(50.0)
+
+
+def test_collective_ms_per_execution():
+    r = _chips(4, 160)
+    ctx = readers.Context(kind="score", trace=r, peaks={}, unit_work=(
+        Work(), Work()), units=1, chips=4)
+    assert r.class_seconds_per_run("prefill_step", "collective") == [
+        pytest.approx(0.001)]
+    assert readers.class_ms(ctx, "score", "prefill_step",
+                            "collective") == pytest.approx(1.0)
+    # a trace with no collective reads nothing
+    assert readers.class_ms(ctx, "score", "prefill_step",
+                            "attention") is None
+    assert readers.class_ms(ctx, "generate", "prefill_step",
+                            "collective") is None
