@@ -50,9 +50,14 @@ class ArchConfig:
     ssm_expand: int = 2
     ssm_conv_width: int = 4
     ssm_chunk: int = 256             # SSD chunk length
+    ssm_ngroups: int = 1             # B/C groups; head k reads group k*G/H
 
-    # --- hybrid (zamba2) ---
-    shared_attn_every: int = 0       # apply shared attention block every N
+    # --- hybrid (zamba2): shared transformer blocks between Mamba layers ---
+    hybrid_layer_ids: tuple[int, ...] = ()  # layers whose Mamba is preceded
+                                     # by a shared-block call (ids past
+                                     # num_layers lie on later stages)
+    num_mem_blocks: int = 1          # shared blocks, called in turn
+    adapter_rank: int = 0            # rank of each call's MLP adapter
 
     # --- encoder-decoder (whisper) ---
     encoder_layers: int = 0          # >0 -> enc-dec model
@@ -76,6 +81,8 @@ class ArchConfig:
     source: str = ""
 
     def __post_init__(self):
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
         if self.num_kv_heads == 0 and self.num_heads:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
         if self.head_dim == 0 and self.num_heads:
@@ -90,6 +97,13 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def shared_calls(self) -> tuple[int, ...]:
+        """The hybrid layers this configuration holds: call ``j`` runs
+        before the Mamba of layer ``shared_calls[j]``, with shared block
+        ``j % num_mem_blocks``."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.num_layers)
 
     @property
     def is_enc_dec(self) -> bool:
@@ -131,27 +145,11 @@ class ArchConfig:
         def ffn(dff):
             return d * dff * (3 if self.gated_mlp else 2)
         if self.family == "ssm":
-            d_in = self.ssm_expand * d
-            nh = d_in // self.ssm_headdim
-            per = (d * (2 * d_in + 2 * self.ssm_state + nh)  # in_proj etc.
-                   + d_in * d                                 # out_proj
-                   + self.ssm_conv_width * (d_in + 2 * self.ssm_state))
-            return n + self.num_layers * (per + d)
+            return n + self.num_layers * self._mamba_layer_params()
         if self.family == "hybrid":
-            d_in = self.ssm_expand * d
-            nh = d_in // self.ssm_headdim
-            per = (d * (2 * d_in + 2 * self.ssm_state + nh) + d_in * d
-                   + self.ssm_conv_width * (d_in + 2 * self.ssm_state)
-                   + 2 * d)                        # mamba block + norms
-            n += self.num_layers * per
-            # one shared transformer block (params counted once):
-            # concat down-proj + attention + MLP
-            hd = self.head_dim
-            n_shared = (2 * d * d
-                        + d * hd * (2 * self.num_heads
-                                    + 2 * self.num_kv_heads)
-                        + ffn(self.d_ff))
-            return n + n_shared
+            return n + self.num_layers * self._mamba_layer_params() + (
+                self.num_mem_blocks * self._shared_block_params()
+                + len(self.shared_calls) * self._call_params())
         per = n_layer_attn + 2 * d
         if self.is_moe:
             moe_layers = self.num_layers - self.first_dense_layers
@@ -176,17 +174,37 @@ class ArchConfig:
                 n += self.patch_size ** 2 * self.image_channels * d + d
         return n
 
+    def _mamba_layer_params(self) -> int:
+        """One Mamba2 layer with its pre-norm."""
+        d = self.d_model
+        d_in = self.ssm_expand * d
+        nh = d_in // self.ssm_headdim
+        bc = 2 * self.ssm_ngroups * self.ssm_state
+        return (d * (2 * d_in + bc + nh)                   # in_proj
+                + d_in * d                                 # out_proj
+                + (self.ssm_conv_width + 1) * (d_in + bc)  # conv + bias
+                + 3 * nh + d_in + d)                       # A, D, dt; norms
+
+    def _shared_block_params(self) -> int:
+        """One zamba2 shared block: attention over concat(h, emb0), its
+        norms and the gated MLP."""
+        d, hd = self.d_model, self.head_dim
+        q, kv = self.num_heads * hd, self.num_kv_heads * hd
+        return (2 * d * (q + 2 * kv) + q * d + 3 * d
+                + d * self.d_ff * (3 if self.gated_mlp else 2))
+
+    def _call_params(self) -> int:
+        """One call's own weights: the MLP adapter and the d x d linear."""
+        d = self.d_model
+        return self.adapter_rank * (d + 2 * self.d_ff) + d * d
+
     def active_param_count(self) -> int:
         """Params touched per token: MoE counts only routed top-k experts;
-        hybrid counts the shared block once per group it is applied to."""
-        if self.family == "hybrid" and self.shared_attn_every:
-            d, hd = self.d_model, self.head_dim
-            n_shared = (2 * d * d
-                        + d * hd * (2 * self.num_heads
-                                    + 2 * self.num_kv_heads)
-                        + d * self.d_ff * (3 if self.gated_mlp else 2))
-            n_groups = -(-self.num_layers // self.shared_attn_every)
-            return self.param_count() + (n_groups - 1) * n_shared
+        hybrid counts a shared block once per call."""
+        if self.family == "hybrid":
+            return self.param_count() + (
+                len(self.shared_calls) - self.num_mem_blocks
+            ) * self._shared_block_params()
         if not self.is_moe:
             return self.param_count()
         d = self.d_model
@@ -200,14 +218,20 @@ class ArchConfig:
 
 
 def reduced(cfg: ArchConfig) -> ArchConfig:
-    """Tiny same-family config for CPU smoke tests."""
+    """Tiny same-family config for CPU smoke tests.  A hybrid keeps its
+    blocks, attention as wide as its 2 * d_model input, and three calls
+    (the first block runs twice) after a Mamba-only layer, each call's
+    layer followed by one more."""
+    hybrid = (dict(num_layers=7, head_dim=64, hybrid_layer_ids=(1, 3, 5),
+                   adapter_rank=min(cfg.adapter_rank, 8))
+              if cfg.hybrid_layer_ids else
+              dict(num_layers=min(cfg.num_layers, 2), head_dim=32))
     return dataclasses.replace(
         cfg,
-        num_layers=min(cfg.num_layers, 2),
+        **hybrid,
         d_model=128,
         num_heads=4,
         num_kv_heads=max(1, min(cfg.num_kv_heads * 4 // max(cfg.num_heads, 1), 4)),
-        head_dim=32,
         d_ff=256,
         moe_d_ff=64 if cfg.moe_d_ff else 0,
         vocab_size=512,
@@ -219,7 +243,7 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
         ssm_state=min(cfg.ssm_state, 16) if cfg.ssm_state else 0,
         ssm_headdim=32 if cfg.ssm_state else 64,
         ssm_chunk=16,
-        shared_attn_every=2 if cfg.shared_attn_every else 0,
+        ssm_ngroups=min(cfg.ssm_ngroups, 2),
         encoder_layers=min(cfg.encoder_layers, 2),
         decoder_len=16 if cfg.is_enc_dec else cfg.decoder_len,
         vision_prefix=8 if cfg.vision_prefix else 0,

@@ -11,6 +11,7 @@ _MODULES = {
     "glm4-9b": "glm4_9b",
     "whisper-small": "whisper_small",
     "zamba2-1.2b": "zamba2_1p2b",
+    "zamba2-7b": "zamba2_7b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "mixtral-8x22b": "mixtral_8x22b",
     "mamba2-130m": "mamba2_130m",

@@ -8,7 +8,8 @@ CONFIG = ArchConfig(
     d_ff=8192, vocab_size=32000,
     head_dim=64,                      # shared block: 32 heads on 2*d concat
     ssm_state=64, ssm_headdim=64, ssm_expand=2, ssm_conv_width=4,
-    shared_attn_every=6,
+    hybrid_layer_ids=tuple(range(6, 38, 6)), num_mem_blocks=1,
+    adapter_rank=128,
     gated_mlp=True, act="gelu", norm="rmsnorm",
     source="arXiv:2411.15242; hf",
 )

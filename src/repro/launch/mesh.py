@@ -26,7 +26,10 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        # Auto axes, as ``Mesh`` makes them: the model's sharding
+        # constraints name mesh axes that only Auto axes accept
+        return jax.make_mesh(shape, axes, axis_types=(
+            jax.sharding.AxisType.Auto,) * len(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)}; "

@@ -104,7 +104,9 @@ def apply_rope(x, cos, sin):
 # Attention (GQA, optional sliding window, optional cross-attention)
 # ----------------------------------------------------------------------
 
-def init_attention(key, cfg, d_model=None):
+def init_attention(key, cfg, d_model=None, d_out=None):
+    """Projections from ``d_model`` (default the model's width) to the
+    heads, and from the heads to ``d_out`` (default ``d_model``)."""
     d = d_model or cfg.d_model
     h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     ks = jax.random.split(key, 4)
@@ -112,7 +114,7 @@ def init_attention(key, cfg, d_model=None):
         "wq": _dense_init(ks[0], (d, h * hd)),
         "wk": _dense_init(ks[1], (d, kv * hd)),
         "wv": _dense_init(ks[2], (d, kv * hd)),
-        "wo": _dense_init(ks[3], (h * hd, d)),
+        "wo": _dense_init(ks[3], (h * hd, d_out or d)),
     }
 
 
@@ -218,18 +220,21 @@ def sdpa(q, k, v, *, causal, window=None, q_offset=0, kv_positions=None,
 
 def apply_attention(p, x, cfg, *, cos_sin=None, kv=None, causal=None,
                     window=None, q_offset=0, kv_positions=None, valid=None,
-                    cross_x=None, residual=None):
+                    cross_x=None, residual=None, q_scale=1.0):
     """Full attention block: projections + RoPE + SDPA + output proj.
 
     cross_x: keys/values come from the encoder stream (whisper decoder).
     ``residual`` is fused into the output projection's deprime store
     (epilogue-carrying contract Plan), saving the separate elementwise
-    read-add pass.
+    read-add pass.  ``q_scale`` multiplies the queries in their
+    projection's deprime (its alpha), so the softmax scale is
+    ``q_scale / sqrt(head_dim)``.
     Returns (out, (k, v)) so callers can build KV caches.
     """
     b, s, d = x.shape
     h, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = facility.contract(DOT, x, p["wq"]).reshape(b, s, h, hd)
+    q = facility.contract(DOT, x, p["wq"], plan=Plan(alpha=q_scale)
+                          ).reshape(b, s, h, hd)
     src = cross_x if cross_x is not None else x
     if kv is None:
         k = facility.contract(DOT, src, p["wk"]).reshape(

@@ -8,7 +8,8 @@ extends to attention-free models: every einsum below routes through the
 facility and lowers to resident-accumulator MXU loops.
 
 Layout: x (B, L, H, P) with H = d_inner / headdim heads, P = headdim,
-N = d_state, single B/C group (ngroups=1).
+N = d_state, G = ``ssm_ngroups`` B/C groups: head k reads group k*G/H,
+and the gated output norm is taken over each group's d_inner/G channels.
 """
 
 from __future__ import annotations
@@ -26,14 +27,14 @@ from repro.parallel.api import shard
 def dims(cfg):
     d_in = cfg.ssm_expand * cfg.d_model
     nheads = d_in // cfg.ssm_headdim
-    conv_dim = d_in + 2 * cfg.ssm_state
+    conv_dim = d_in + 2 * cfg.ssm_ngroups * cfg.ssm_state
     return d_in, nheads, conv_dim
 
 
 def init_mamba2(key, cfg):
     d = cfg.d_model
     d_in, nheads, conv_dim = dims(cfg)
-    n = cfg.ssm_state
+    n = cfg.ssm_ngroups * cfg.ssm_state
     ks = jax.random.split(key, 4)
     return {
         # fused input projection: [z, x, B, C, dt]
@@ -60,7 +61,7 @@ def mamba2_axes(cfg):
 
 def _split_proj(proj, cfg):
     d_in, nheads, _ = dims(cfg)
-    n = cfg.ssm_state
+    n = cfg.ssm_ngroups * cfg.ssm_state
     z, xbc_dt = jnp.split(proj, [d_in], axis=-1)
     xbc, dt = jnp.split(xbc_dt, [d_in + 2 * n], axis=-1)
     return z, xbc, dt
@@ -112,8 +113,10 @@ def _segsum(dA):
 def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
     """SSD scan (ssd_minimal_discrete, Mamba2 paper listing 1).
 
-    x (b,l,h,p); dt (b,l,h) [post-softplus]; A (h,) negative decay;
-    B, C (b,l,n).  Returns y (b,l,h,p) [, final_state (b,h,n,p)] — the
+    x (b,l,h,p); dt (b,l,h) [post-softplus]; A (h,) negative decay, or
+    any array that broadcasts against dt; B, C (b,l,n); D (h,), or any
+    array whose ``D[:, None]`` broadcasts against (b,nc,chunk,h,p).
+    Returns y (b,l,h,p) [, final_state (b,h,n,p)] — the
     final state is the prefill->decode handoff.
     """
     b, l, h, p = x.shape
@@ -175,6 +178,45 @@ def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
     return y
 
 
+def _fold_groups(t, groups):
+    """(b, l, groups * k, ...) -> (b * groups, l, k, ...): each group's
+    part of the third axis becomes a sequence of its own."""
+    b, l = t.shape[:2]
+    t = t.reshape(b, l, groups, -1, *t.shape[3:])
+    return jnp.swapaxes(t, 1, 2).reshape(b * groups, l, *t.shape[3:])
+
+
+def ssd_grouped(x, dt, A, B, C, D, chunk, groups):
+    """``ssd_chunked`` with ``groups`` B/C groups, B, C (b,l,groups*n):
+    the heads of one group read only its B and C, so each group's heads
+    run as one more sequence of the batch.  Returns y and the final
+    state."""
+    if groups == 1:
+        return ssd_chunked(x, dt, A, B, C, D, chunk, return_state=True)
+    b, l, h, p = x.shape
+    k = h // groups
+
+    def per_seq(v, *tail):   # (h,) -> (b * groups, 1, k, *tail)
+        v = jnp.broadcast_to(v.reshape(groups, k), (b, groups, k))
+        return v.reshape(b * groups, 1, k, *tail)
+
+    y, final = ssd_chunked(
+        _fold_groups(x, groups), _fold_groups(dt, groups), per_seq(A),
+        _fold_groups(B, groups), _fold_groups(C, groups), per_seq(D, 1),
+        chunk, return_state=True)
+    y = jnp.swapaxes(y.reshape(b, groups, l, k, p), 1, 2).reshape(b, l, h, p)
+    return y, final.reshape(b, h, *final.shape[2:])
+
+
+def _group_rms(g, groups, eps):
+    """g over its root mean square, taken over each of ``groups`` equal
+    parts of the last axis."""
+    if groups > 1:
+        return _group_rms(g.reshape(*g.shape[:-1], groups, -1), 1,
+                          eps).reshape(g.shape)
+    return g * jax.lax.rsqrt((g * g).mean(-1, keepdims=True) + eps)
+
+
 def _write_conv(conv_all, conv_state, layer):
     return jax.lax.dynamic_update_index_in_dim(
         conv_all, conv_state.astype(conv_all.dtype), layer, 0)
@@ -227,7 +269,8 @@ def apply_mamba2(p, x, cfg, state=None, layer=None):
     """
     b, l, d = x.shape
     d_in, nheads, conv_dim = dims(cfg)
-    n = cfg.ssm_state
+    groups = cfg.ssm_ngroups
+    n = groups * cfg.ssm_state
     proj = facility.contract(DOT, x, p["in_proj"])
     z, xbc, dt_raw = _split_proj(proj, cfg)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])
@@ -240,8 +283,7 @@ def apply_mamba2(p, x, cfg, state=None, layer=None):
         xh = xs.reshape(b, l, nheads, cfg.ssm_headdim)
         xh = shard(xh, "batch", None, "ssm_heads", None)
         chunk = min(cfg.ssm_chunk, l)   # short-sequence smoke/training
-        y, final = ssd_chunked(xh, dt, A, B, C, p["D"], chunk,
-                               return_state=True)
+        y, final = ssd_grouped(xh, dt, A, B, C, p["D"], chunk, groups)
         # prefill -> decode handoff: final SSM state + conv tail
         w = cfg.ssm_conv_width
         new_state = {"ssm": final,
@@ -260,34 +302,39 @@ def apply_mamba2(p, x, cfg, state=None, layer=None):
             conv_all = _in_place(_write_conv, conv_all, conv_state, layer)
         xs, B, C = jnp.split(xbc, [d_in, d_in + n], axis=-1)
         xh = xs.reshape(b, l, nheads, cfg.ssm_headdim)
-        # single-token recurrent update: s <- exp(dt A) s + dt B x
+        # single-token recurrent update: s <- exp(dt A) s + dt B x; each
+        # group's heads, with its B and C, as one more row of the batch
         dA = jnp.exp(dt[:, 0] * A)                        # (b,h)
         ssm_all = state["ssm"]                            # (L,b,h,n,p)
+        bg, k = b * groups, nheads // groups
         with jax.named_scope("ssm.state"):
             sstate = jax.lax.dynamic_index_in_dim(ssm_all, layer,
                                                   keepdims=False)
             upd = facility.contract(
-                "bn,bhp->bhnp", B[:, 0],
-                (xh[:, 0] * dt[:, 0, :, None]).astype(x.dtype),
-                plan=Plan(out_dtype=jnp.float32))
+                "bn,bhp->bhnp", B[:, 0].reshape(bg, -1),
+                (xh[:, 0] * dt[:, 0, :, None]).astype(x.dtype).reshape(
+                    bg, k, -1),
+                plan=Plan(out_dtype=jnp.float32)).reshape(sstate.shape)
             ssm_all, sstate = _in_place(_update_state, ssm_all, sstate, dA,
                                         upd, layer)
-            y = facility.contract("bn,bhnp->bhp", C[:, 0],
-                                  sstate.astype(x.dtype))
+            y = facility.contract(
+                "bn,bhnp->bhp", C[:, 0].reshape(bg, -1),
+                sstate.astype(x.dtype).reshape(bg, k, *sstate.shape[2:])
+            ).reshape(b, nheads, -1)
         y = (y.astype(jnp.float32)
              + xh[:, 0].astype(jnp.float32) * p["D"][:, None])
         y = y[:, None].astype(x.dtype)
         new_state = {"ssm": ssm_all, "conv": conv_all}
 
     y = y.reshape(b, l, d_in)
-    # gated RMSNorm (mamba2 block output norm).  Its mean runs over all of
-    # d_in on one device: a mesh-sharded d_in would be summed as per-shard
-    # partials plus an all-reduce, which rounds differently from one
-    # device's sum.
+    # gated RMSNorm (mamba2 block output norm), over each group's d_in/G
+    # channels.  Its mean runs on one device: a mesh-sharded d_in would be
+    # summed as per-shard partials plus an all-reduce, which rounds
+    # differently from one device's sum.
     g = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     g = shard(g, "batch", None, None)
     gf = g.astype(jnp.float32)
-    g = (gf * jax.lax.rsqrt((gf * gf).mean(-1, keepdims=True) + cfg.norm_eps)
+    g = (_group_rms(gf, groups, cfg.norm_eps)
          * p["norm_scale"]).astype(x.dtype)
     return facility.contract(DOT, g, p["out_proj"]), new_state
 

@@ -2,7 +2,7 @@
 
 Families (DESIGN.md section 4): dense (llama lineage incl. GQA + SWA),
 moe (mixtral, deepseek-moe fine-grained + shared experts), ssm (mamba2),
-hybrid (zamba2: mamba backbone + shared attention block), audio (whisper
+hybrid (zamba2: mamba backbone + shared transformer blocks), audio (whisper
 enc-dec, conv audio stem), vlm (qwen2-vl backbone, M-RoPE, conv
 patch-embed vision stem).
 
@@ -135,11 +135,49 @@ def _stack_init(key, cfg, kind, n):
         jax.random.split(key, n))
 
 
-def _stack_axes(cfg, kind):
+def _stacked(axes):
     """Prefix every leaf's axes with the stacked layer axis."""
-    return jax.tree.map(lambda ax: ("layers",) + ax, _layer_axes(cfg, kind),
+    return jax.tree.map(lambda ax: ("layers",) + ax, axes,
                         is_leaf=lambda x: isinstance(x, tuple) and all(
                             isinstance(e, (str, type(None))) for e in x))
+
+
+def _stack_axes(cfg, kind):
+    return _stacked(_layer_axes(cfg, kind))
+
+
+# zamba2: ``num_mem_blocks`` shared transformer blocks, called in turn
+# before the Mamba of each layer in ``cfg.shared_calls``; each call has its
+# own MLP adapter and d x d output linear.
+
+def _init_shared_block(key, cfg):
+    """Attention from concat(h, emb0), 2 d wide, back to d; the gated
+    MLP."""
+    d = cfg.d_model
+    ks = jax.random.split(key, 2)
+    return {"attn_norm": L.init_norm(cfg, 2 * d),
+            "attn": L.init_attention(ks[0], cfg, 2 * d, d_out=d),
+            "mlp_norm": L.init_norm(cfg), "mlp": L.init_mlp(ks[1], cfg)}
+
+
+def _init_call(key, cfg):
+    """A call's rank-``adapter_rank`` adapter on the MLP's gate and up
+    projections, and its linear."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+    ks = jax.random.split(key, 4)
+    return {"adapter_in": L._dense_init(ks[0], (d, r)),
+            "adapter_gate": L._dense_init(ks[1], (r, f)),
+            "adapter_up": L._dense_init(ks[2], (r, f)),
+            "linear": L._dense_init(ks[3], (d, d))}
+
+
+def _shared_axes(cfg):
+    return {"shared": _stacked({
+                "attn_norm": L.norm_axes(cfg), "attn": L.attention_axes(cfg),
+                "mlp_norm": L.norm_axes(cfg), "mlp": L.mlp_axes(cfg)}),
+            "calls": _stacked({
+                "adapter_in": ("embed", None), "adapter_gate": (None, "mlp"),
+                "adapter_up": (None, "mlp"), "linear": ("embed", None)})}
 
 
 def _main_kind(cfg) -> str:
@@ -163,15 +201,11 @@ def init_params(cfg, key) -> Params:
     if cfg.first_dense_layers:
         p["first_dense"] = _stack_init(ks[2], cfg, "dense",
                                        cfg.first_dense_layers)
-    if cfg.shared_attn_every:
-        # zamba2: one shared transformer block; input is concat(h, emb0)
-        p["shared_attn"] = {
-            "in_proj": L._dense_init(ks[3], (2 * cfg.d_model, cfg.d_model)),
-            "attn_norm": L.init_norm(cfg),
-            "attn": L.init_attention(ks[4], cfg),
-            "mlp_norm": L.init_norm(cfg),
-            "mlp": L.init_mlp(ks[5], cfg),
-        }
+    if kind == "hybrid":
+        p["shared"] = jax.vmap(lambda k: _init_shared_block(k, cfg))(
+            jax.random.split(ks[3], cfg.num_mem_blocks))
+        p["calls"] = jax.vmap(lambda k: _init_call(k, cfg))(
+            jax.random.split(ks[4], len(cfg.shared_calls)))
     if cfg.is_enc_dec:
         p["encoder"] = {
             "layers": _stack_init(ks[6], cfg, "encoder", cfg.encoder_layers),
@@ -217,12 +251,8 @@ def param_axes(cfg):
     }
     if cfg.first_dense_layers:
         p["first_dense"] = _stack_axes(cfg, "dense")
-    if cfg.shared_attn_every:
-        p["shared_attn"] = {
-            "in_proj": ("embed", None),
-            "attn_norm": L.norm_axes(cfg), "attn": L.attention_axes(cfg),
-            "mlp_norm": L.norm_axes(cfg), "mlp": L.mlp_axes(cfg),
-        }
+    if kind == "hybrid":
+        p.update(_shared_axes(cfg))
     if cfg.is_enc_dec:
         p["encoder"] = {"layers": _stack_axes(cfg, "encoder"),
                         "norm": L.norm_axes(cfg)}
@@ -278,45 +308,85 @@ def _apply_dense_block(bp, h, cfg, *, cos_sin, is_moe, causal=None,
     return h, aux, kv_out, cross_kv
 
 
-def _apply_ssm_block(bp, h, cfg, state=None, layer=None):
+def _apply_ssm_block(bp, h, cfg, state=None, layer=None, t=None):
+    """h + Mamba(RMSNorm(h)); in a hybrid layer the norm reads h + t, where
+    t is the shared call's output."""
     with jax.named_scope("block.ssm"):
-        hn = L.apply_norm(bp["norm"], h, cfg)
+        hn = L.apply_norm(bp["norm"], h if t is None else h + t, cfg)
         out, new_state = M2.apply_mamba2(bp["mamba"], hn, cfg, state=state,
                                          layer=layer)
         return _residual_shard(h + out), new_state
 
 
-def _ssm_decode_layers(layers, h, cfg, ssm, conv, start=0):
+def _ssm_decode_layers(layers, h, cfg, ssm, conv):
     """Decode through a stack of SSM blocks, the state carried whole:
-    block ``i`` updates slice ``start + i`` of the stacked ``ssm`` and
-    ``conv`` buffers in place (mamba2.apply_mamba2)."""
+    block ``i`` updates slice ``i`` of the stacked ``ssm`` and ``conv``
+    buffers in place (mamba2.apply_mamba2)."""
     def body(carry, lp):
         hh, i, ssm, conv = carry
         hh, st = _apply_ssm_block(lp, hh, cfg,
                                   state={"ssm": ssm, "conv": conv}, layer=i)
         return (hh, i + 1, st["ssm"], st["conv"]), None
     (h, _, ssm, conv), _ = layer_scan(
-        body, (h, jnp.asarray(start, jnp.int32), ssm, conv), layers)
+        body, (h, jnp.asarray(0, jnp.int32), ssm, conv), layers)
     return h, ssm, conv
 
 
-def _apply_shared_attn(sp, h, emb0, cfg, *, cos_sin, kv=None, q_offset=0,
-                       kv_positions=None, valid=None):
-    """zamba2 shared block: operates on concat(h, original embedding)."""
-    from repro.core import facility
+# The shared blocks' softmax scale is (head_dim / 2) ** -0.5, as published:
+# the queries take the extra sqrt(2) in their projection.
+_SHARED_Q_SCALE = 2.0 ** 0.5
+
+
+def _shared_attn(sp, h, emb0, cfg, **attn):
+    """A shared block's attention over RMSNorm(concat(h, emb0)): (out,
+    (k, v)), out d wide.  No residual inside the block."""
     with jax.named_scope("block.attn"):
-        hin = facility.contract(facility.DOT,
-                                jnp.concatenate([h, emb0], axis=-1),
-                                sp["in_proj"])
-        hn = L.apply_norm(sp["attn_norm"], hin, cfg)
-        a, kv_out = L.apply_attention(sp["attn"], hn, cfg, cos_sin=cos_sin,
-                                      kv=kv, q_offset=q_offset,
-                                      kv_positions=kv_positions, valid=valid)
-        hin = hin + a
+        x = L.apply_norm(sp["attn_norm"], jnp.concatenate([h, emb0], -1),
+                         cfg)
+        return L.apply_attention(sp["attn"], x, cfg, q_scale=_SHARED_Q_SCALE,
+                                 **attn)
+
+
+def _shared_mlp(sp, cp, a, cfg):
+    """The rest of a call: RMSNorm, the gated MLP whose gate and up
+    projections each add the call's adapter B(A a) (the accumulator's
+    seed, so the gate's activation sees the sum), and the call's linear.
+    Returns t, which the hybrid layer adds to its Mamba's input."""
+    from repro.core import facility
+    from repro.core.facility import DOT, Epilogue, Plan
     with jax.named_scope("block.mlp"):
-        m = L.apply_mlp(sp["mlp"], L.apply_norm(sp["mlp_norm"], hin, cfg),
-                        cfg)
-        return _residual_shard(h + hin + m)
+        a = L.apply_norm(sp["mlp_norm"], a, cfg)
+        lo = facility.contract(DOT, a, cp["adapter_in"])
+        f32 = Plan(out_dtype=jnp.float32)
+        g = facility.contract(
+            DOT, a, sp["mlp"]["w1"],
+            acc=facility.contract(DOT, lo, cp["adapter_gate"], plan=f32),
+            plan=Plan(epilogue=Epilogue(activation=cfg.act)))
+        u = facility.contract(
+            DOT, a, sp["mlp"]["w3"],
+            acc=facility.contract(DOT, lo, cp["adapter_up"], plan=f32))
+        m = facility.contract(DOT, g * u, sp["mlp"]["w2"])
+        return facility.contract(DOT, m, cp["linear"])
+
+
+def _pick(tree, i):
+    """Entry ``i`` of every leaf of a stacked tree."""
+    return jax.tree.map(lambda w: w[i], tree)
+
+
+def _call_params(params, cfg, j):
+    """Shared block ``j % num_mem_blocks`` and call ``j``'s own weights."""
+    return (_pick(params["shared"], j % cfg.num_mem_blocks),
+            _pick(params["calls"], j))
+
+
+def _scan_layers(body, carry, params, lo, hi):
+    """``layer_scan`` of ``body(carry, i, layer i's params)`` over layers
+    lo .. hi-1, each layer's weights read from the whole stack inside the
+    loop: a static slice of the stack would be a copy of it (and of its
+    bfloat16 cast).  Returns the carry and the stacked outputs."""
+    return layer_scan(lambda c, i: body(c, i, _pick(params["layers"], i)),
+                      carry, jnp.arange(lo, hi))
 
 
 # ======================================================================
@@ -483,27 +553,51 @@ def forward(params, batch, cfg, *, collect_cache: bool = False):
 
 
 def _run_hybrid(params, h, emb0, cfg, cos_sin, collect_cache, caches):
-    """zamba2: groups of mamba layers with a shared attention block."""
-    every = cfg.shared_attn_every
-    n = cfg.num_layers
-    n_groups = -(-n // every)
-    lp_all = params["layers"]
-    shared_kvs = []
-    start = 0
-    for g in range(n_groups):
-        size = min(every, n - start)
-        group = jax.tree.map(lambda a: a[start:start + size], lp_all)
+    """zamba2: the Mamba layers, each of ``cfg.shared_calls`` preceded by
+    a call of a shared block: h = h + Mamba(RMSNorm(h + t)).  Collects
+    every layer's SSM and conv state and each call's K/V."""
+    @_remat
+    def body(carry, i, lp):
+        h, t = carry
+        h, st = _apply_ssm_block(lp, h, cfg, t=t)
+        return (h, jnp.zeros_like(t)), (st if collect_cache else None)
 
-        def body(carry, lp):
-            hh, _ = _apply_ssm_block(lp, carry, cfg)
-            return hh, None
-        body = _remat(body)
-        h, _ = layer_scan(body, h, group)
-        h_kv = _apply_shared_attn(params["shared_attn"], h, emb0, cfg,
-                                  cos_sin=cos_sin)
-        h = h_kv
-        start += size
+    def call(j, h):
+        sp, cp = _call_params(params, cfg, j)
+        a, kv = _shared_attn(sp, h, emb0, cfg, cos_sin=cos_sin)
+        return _shared_mlp(sp, cp, a, cfg), kv
+
+    (h, _), states, kvs = _hybrid_layers(body, call, (h, jnp.zeros_like(h)),
+                                         params, cfg)
+    if collect_cache:
+        stacked = jax.tree.map(lambda *a: jnp.concatenate(a), *states)
+        caches["ssm"], caches["conv"] = stacked["ssm"], stacked["conv"]
+        caches["kv"] = jax.tree.map(lambda *a: jnp.stack(a), *kvs)
     return h
+
+
+def _hybrid_layers(body, call, carry, params, cfg):
+    """The hybrid's layer order, for the forward and the decode step alike:
+    the layers before the first call, then for each call ``j`` at layer
+    ``i``, ``t, out = call(j, h)`` and a ``layer_scan`` of ``body`` over
+    layers i .. (the next call's layer) - 1, whose carry, (h, t, ...),
+    takes t to layer i; ``body`` gives the next layer a zero t.  (Each
+    scan of more than one layer stays a loop, so a decode step updates
+    the stacked state in place.)  Returns the carry, the scans' stacked
+    outputs and the calls' outs."""
+    calls = cfg.shared_calls
+    bounds = calls + (cfg.num_layers,)
+    states, outs = [], []
+    if bounds[0]:
+        carry, ys = _scan_layers(body, carry, params, 0, bounds[0])
+        states.append(ys)
+    for j, i in enumerate(calls):
+        t, out = call(j, carry[0])
+        carry, ys = _scan_layers(body, (carry[0], t) + carry[2:], params, i,
+                                 bounds[j + 1])
+        states.append(ys)
+        outs.append(out)
+    return carry, states, outs
 
 
 # ======================================================================
@@ -570,8 +664,9 @@ def init_cache(cfg, batch: int, seq_len: int, dtype=jnp.bfloat16):
                               cfg.ssm_headdim), jnp.float32)
         c["conv"] = jnp.zeros((cfg.num_layers, batch,
                                cfg.ssm_conv_width - 1, conv_dim), dtype)
-        c["k"] = jnp.zeros((batch, clen, cfg.num_kv_heads, cfg.head_dim),
-                           dtype)  # shared attn block cache (one block)
+        # one ring for each call of a shared block
+        c["k"] = jnp.zeros((len(cfg.shared_calls), batch, clen,
+                            cfg.num_kv_heads, cfg.head_dim), dtype)
         c["v"] = jnp.zeros_like(c["k"])
         c["pos"] = jnp.full((clen,), -1, jnp.int32)
     return c
@@ -600,8 +695,8 @@ def cache_axes(cfg):
     if kind == "hybrid":
         c["ssm"] = ("layers", "batch", "ssm_heads", None, None)
         c["conv"] = ("layers", "batch", None, "mlp")
-        c["k"] = ("batch", "seq_kv", None, None)
-        c["v"] = ("batch", "seq_kv", None, None)
+        c["k"] = kv_ax
+        c["v"] = kv_ax
         c["pos"] = (None,)
     return c
 
@@ -714,51 +809,32 @@ def decode_step(params, cache, tokens, cfg):
         slot = cur % clen
         kv_positions = cache["pos"].at[slot].set(cur)[None]
         valid = kv_positions >= 0
-        every = cfg.shared_attn_every
-        n = cfg.num_layers
-        ssm, conv = M2.own_decode_state(cache)
-        start = 0
-        k_c, v_c = cache["k"], cache["v"]
-        while start < n:
-            size = min(every, n - start)
-            group = jax.tree.map(lambda a: a[start:start + size],
-                                 params["layers"])
-            h, ssm, conv = _ssm_decode_layers(group, h, cfg, ssm, conv,
-                                              start)
-            # shared attention with its ring cache
-            sp = params["shared_attn"]
-            from repro.core import facility
-            with jax.named_scope("block.attn"):
-                hin = facility.contract(facility.DOT,
-                                        jnp.concatenate([h, emb0], axis=-1),
-                                        sp["in_proj"])
-                hn = L.apply_norm(sp["attn_norm"], hin, cfg)
-                knew = facility.contract(
-                    facility.DOT, hn, sp["attn"]["wk"]).reshape(
-                    b, 1, cfg.num_kv_heads, cfg.head_dim)
-                vnew = facility.contract(
-                    facility.DOT, hn, sp["attn"]["wv"]).reshape(
-                    b, 1, cfg.num_kv_heads, cfg.head_dim)
-                knew = L.apply_rope(knew, cos_sin[2], cos_sin[3])
-                with jax.named_scope("kv.write"):
-                    k_c = jax.lax.dynamic_update_slice_in_dim(k_c, knew,
-                                                              slot, 1)
-                    v_c = jax.lax.dynamic_update_slice_in_dim(v_c, vnew,
-                                                              slot, 1)
-                a, _ = L.apply_attention(sp["attn"], hn, cfg,
-                                         cos_sin=cos_sin, kv=(k_c, v_c),
-                                         q_offset=cur,
-                                         kv_positions=kv_positions,
-                                         valid=valid)
-                hin = hin + a
-            with jax.named_scope("block.mlp"):
-                m = L.apply_mlp(sp["mlp"],
-                                L.apply_norm(sp["mlp_norm"], hin, cfg), cfg)
-                h = h + hin + m
-            start += size
-        new_cache["ssm"], new_cache["conv"] = ssm, conv
-        new_cache["k"], new_cache["v"] = k_c, v_c
-        new_cache["pos"] = kv_positions[0]
+        rings = {"k": cache["k"], "v": cache["v"]}
+
+        def body(carry, i, lp):
+            h, t, ssm, conv = carry
+            h, st = _apply_ssm_block(lp, h, cfg,
+                                     state={"ssm": ssm, "conv": conv},
+                                     layer=i, t=t)
+            return (h, jnp.zeros_like(t), st["ssm"], st["conv"]), None
+
+        def call(j, h):
+            """Call ``j`` with its own ring."""
+            sp, cp = _call_params(params, cfg, j)
+            k_c, v_c = _ring_insert(sp, jnp.concatenate([h, emb0], -1),
+                                    rings["k"][j], rings["v"][j], slot,
+                                    cos_sin, cfg)
+            rings["k"] = rings["k"].at[j].set(k_c)
+            rings["v"] = rings["v"].at[j].set(v_c)
+            a, _ = _shared_attn(sp, h, emb0, cfg, cos_sin=cos_sin,
+                                kv=(k_c, v_c), q_offset=cur,
+                                kv_positions=kv_positions, valid=valid)
+            return _shared_mlp(sp, cp, a, cfg), None
+
+        (h, _, ssm, conv), _, _ = _hybrid_layers(
+            body, call, (h, jnp.zeros_like(h), *M2.own_decode_state(cache)),
+            params, cfg)
+        new_cache.update(rings, ssm=ssm, conv=conv, pos=kv_positions[0])
 
     h = L.apply_norm(params["final_norm"], h, cfg)
     logits = L.logits(params["embed"], h, cfg)

@@ -28,10 +28,11 @@ from repro.train import steps as S
 
 def _cfg(name):
     if name == "zamba2-1.2b-groups":
-        # layer groups of 2, 2 and 1 between the shared-attention blocks:
-        # the state is carried across groups, each from its own index
+        # layer groups of 2, 2 and 1, the shared block called before the
+        # second and the third: the state is carried across groups, each
+        # from its own index
         return dataclasses.replace(reduced(get("zamba2-1.2b")),
-                                   num_layers=5, shared_attn_every=2)
+                                   num_layers=5, hybrid_layer_ids=(2, 4))
     return reduced(get(name))
 
 
