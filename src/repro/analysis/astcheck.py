@@ -183,14 +183,14 @@ class Checker(ast.NodeVisitor):
     def _check_import_target(self, node: ast.AST, target: str) -> None:
         if not target or not target.startswith("repro"):
             return
-        # attn-op-class: models never import the attention kernel module.
-        if self.attn_client and (
-                target == rules.ATTN_KERNEL_MODULE
-                or target.startswith(rules.ATTN_KERNEL_MODULE + ".")):
-            self.report("attn-op-class", node,
-                        "models must dispatch attention through "
-                        "facility.contract(facility.ATTN, ...), not "
-                        f"import `{target}`")
+        # attn-op-class: models never import an op-class kernel module.
+        for kernel, spec in rules.OP_CLASS_KERNEL_MODULES.items():
+            if self.attn_client and (target == kernel
+                                     or target.startswith(kernel + ".")):
+                self.report("attn-op-class", node,
+                            "models must dispatch through "
+                            f"facility.contract({spec}, ...), not "
+                            f"import `{target}`")
         # layer-stratification over the mapped spine.
         r, t = self.stratum, rules.stratum_of(target)
         if r is None or t is None:

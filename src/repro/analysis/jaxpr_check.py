@@ -19,7 +19,8 @@ eqn params, nothing executes), and audits the equations:
   pre-masked.
 - ``jaxpr-vmem-budget``: every autotune candidate's full BlockSpec
   residency (working set + out tile) fits physical VMEM before anything
-  is compiled.
+  is compiled, and the SSD kernel's shape rule, VMEM residency included,
+  admits every registered SSM architecture's chunk.
 
 Taint flow maps positionally through ``jit`` boundaries (``contract``
 jits internally) and stops at ``pallas_call``: in-kernel ``select_n`` on
@@ -51,6 +52,7 @@ AUDIT_GERS = {
     "gemm.saturating": (Ger.I16GER2,),
     "conv": (Ger.F32GER,),
     "attn": (Ger.BF16GER2,),
+    "ssd": (Ger.BF16GER2,),
 }
 
 
@@ -181,6 +183,33 @@ def check_vmem_candidates(cfgs, pol, where: str,
     return out
 
 
+def check_ssd_vmem(where: str) -> list[Finding]:
+    """The SSD kernel's shape rule (``lowering.ssd_shape_fits``: tiles,
+    lanes and VMEM residency) holds, with bf16 C, B, x and y, at the
+    chunk, state, heads per B/C group and head width of every registered
+    SSM architecture: where it fails, dispatch would leave the
+    architecture on the XLA chain."""
+    from repro import configs
+    bf16 = jnp.bfloat16
+    out = []
+    for name in configs.ARCHS:
+        cfg = configs.get(name)
+        if not cfg.ssm_state:
+            continue
+        heads = cfg.ssm_expand * cfg.d_model // cfg.ssm_headdim
+        h = heads // cfg.ssm_ngroups
+        if not lowering.ssd_shape_fits(
+                cfg.ssm_chunk, cfg.ssm_state, h, cfg.ssm_headdim,
+                ger=Ger.BF16GER2, c_dtype=bf16, x_dtype=bf16,
+                out_dtype=bf16):
+            out.append(Finding(
+                "jaxpr-vmem-budget", where, 0,
+                f"{name}: the SSD kernel does not take chunk "
+                f"{cfg.ssm_chunk}, state {cfg.ssm_state}, {h} heads of "
+                f"{cfg.ssm_headdim} within {tiling.VMEM_BUDGET} B VMEM"))
+    return out
+
+
 # ----------------------------------------------------------------------
 # The audit driver: build the matrix from the registry, trace each cell
 # ----------------------------------------------------------------------
@@ -195,6 +224,12 @@ def _operands(op_class, ger, rng):
     if op_class == "conv":
         return (jnp.asarray(rng.normal(size=(1, 8, 8, 4)), f32),
                 jnp.asarray(rng.normal(size=(3, 3, 4, 8)), f32))
+    if op_class == "ssd":
+        # one 128-row chunk (the kernel's row tile) of 8 heads of 16
+        cum = jnp.cumsum(-jnp.asarray(rng.random((1, 1, 8, 128)), f32), -1)
+        return (jnp.asarray(rng.normal(size=(1, 1, 128, 16)), f32),
+                jnp.asarray(rng.normal(size=(1, 1, 128, 16)), f32),
+                jnp.asarray(rng.normal(size=(1, 1, 128, 8, 16)), f32), cum)
     x = jnp.asarray(rng.normal(size=(16, 64)), f32)
     y = jnp.asarray(rng.normal(size=(64, 32)), f32)
     return (x, y)
@@ -212,6 +247,10 @@ def _trace_cell(backend, op_class, ger, cfg, rng):
                              out_dtype=jnp.float32)
         fn = lambda a, b: facility.contract(
             facility.CONV2D, a, b, plan=plan)
+    elif op_class == "ssd":
+        plan = lowering.Plan(ger=ger, backend=backend)
+        fn = lambda c, b, x, cum: facility.contract(
+            facility.SSD, c, b, x, cum, plan=plan)
     elif op_class == "gemm.masked":
         plan = lowering.Plan(ger=ger, backend=backend,
                              out_dtype=precision.policy(ger).acc_dtype)
@@ -302,6 +341,11 @@ def audit_registry(verbose: bool = False):
                     (2048, 2048, 2048), (8192, 8192, 8192)):
             findings.extend(check_vmem_candidates(
                 autotune.candidate_blocks(*mnk, ger), pol, where))
+
+    # the SSD kernel's residency at each registered SSM architecture.
+    where = "<jaxpr:vmem/ssd>"
+    audited.append(where)
+    findings.extend(check_ssd_vmem(where))
 
     if verbose:
         for w in audited:

@@ -57,9 +57,10 @@ RULES: dict[str, Rule] = {r.id: r for r in [
          "jax.vmap / vectorize (one pallas_call per contraction)",
          "PR 4"),
     Rule("attn-op-class",
-         "attention is a registry op-class: models dispatch through "
-         "facility.contract(facility.ATTN, ...) and never import "
-         "kernels.mma_attention directly",
+         "attention and the SSD chunk scan are registry op-classes: "
+         "models dispatch through facility.contract(facility.ATTN, ...) "
+         "and facility.contract(facility.SSD, ...) and never import "
+         "kernels.mma_attention or kernels.mma_ssd directly",
          "PR 5"),
     Rule("pack-once",
          "layout changes are paid once at pack time (core/packing.py): "
@@ -152,9 +153,11 @@ GRID_OWNS_BATCH_MODULES = frozenset({"repro.core.lowering"})
 VMAP_NAMES = frozenset({"jax.vmap", "jax.numpy.vectorize",
                         "numpy.vectorize"})
 
-# attn-op-class: modules forbidden to import the attention kernel module.
+# attn-op-class: modules forbidden to import the op-class kernel modules,
+# each mapped to the facility spec that dispatches it.
 ATTN_FORBIDDEN_PREFIX = "repro.models"
-ATTN_KERNEL_MODULE = "repro.kernels.mma_attention"
+OP_CLASS_KERNEL_MODULES = {"repro.kernels.mma_attention": "facility.ATTN",
+                           "repro.kernels.mma_ssd": "facility.SSD"}
 
 # pack-once: the dispatch hot path (lowering) must not unpack/pack or
 # swapaxes operands per call; the GEMM/conv kernels must not transpose

@@ -60,12 +60,18 @@ CONV2D = lowering.CONV2D                      # "nhwc,hwio->nhwo"
 CONV1D = lowering.CONV1D                      # "nlc,lio->nlo"
 CONV1D_DEPTHWISE = lowering.CONV1D_DEPTHWISE  # "nlc,lc->nlc"
 
-# Canonical fused-attention spec (the attn op-class): the one three-operand
+# Canonical fused-attention spec (the attn op-class): a three-operand
 # builtin — softmax couples the score and value contractions, so no
 # two-operand spec can name it.  q (B, Sq, H, D); k, v (B, Sk, KVH, D);
 # causal/window/q_offset ride in the Plan, the (B, Sk) valid-slot
 # predicate as ``masks=(valid,)``.
 ATTN = lowering.ATTN                          # "bqhd,bkhd->bqhd"
+
+# Canonical SSD spec (the ssd op-class): the Mamba2 chunk scan's
+# intra-chunk branch, a four-operand builtin.  C, B (B, NC, L, N); x
+# (B, NC, L, H, P); cum, the (B, NC, H, L) within-chunk cumulative
+# log-decays, whose differences make the causal decay mask.
+SSD = lowering.SSD                     # "bcln,bcsn,bcshp,bchl->bclhp"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,7 +122,8 @@ def configure(cfg: FacilityConfig):
 
 
 def contract(spec: str, x: jnp.ndarray, y: jnp.ndarray,
-             z: jnp.ndarray | None = None, *,
+             z: jnp.ndarray | None = None,
+             w: jnp.ndarray | None = None, *,
              plan: Plan | None = None,
              acc: jnp.ndarray | None = None,
              bias: jnp.ndarray | None = None,
@@ -136,19 +143,22 @@ def contract(spec: str, x: jnp.ndarray, y: jnp.ndarray,
     lowering applies them to the streamed panels in-kernel, never
     pre-masking operands in HBM).
 
-    ``z`` is the value operand of the canonical :data:`ATTN` spec — the
-    facility's one three-operand builtin (``contract(facility.ATTN, q, k,
+    ``z`` is the value operand of the canonical :data:`ATTN` spec — a
+    three-operand builtin (``contract(facility.ATTN, q, k,
     v, plan=Plan(causal=..., window=..., q_offset=...))``); there,
-    ``masks`` is the 1-tuple ``(valid,)`` filled-KV-slot predicate.
+    ``masks`` is the 1-tuple ``(valid,)`` filled-KV-slot predicate.  The
+    canonical :data:`SSD` spec takes four operands (``contract(
+    facility.SSD, C, B, x, cum)``): ``z`` is x and ``w`` the cumulative
+    log-decays.
 
     Dispatch goes through the lowering registry (``repro.core.lowering``):
     specs that normalize to (batched) 2-D GEMMs reach the autotuned Pallas
     kernels — batch rides as a grid dimension, one ``pallas_call`` per
     contraction — or the shardable ``lax.dot_general`` lowering; the
-    canonical conv/attn specs reach their op-classes; everything else
+    canonical conv/attn/ssd specs reach their op-classes; everything else
     falls back to the general einsum lowering.
     """
-    return lowering.execute(spec, x, y, z, cfg=current(), plan=plan,
+    return lowering.execute(spec, x, y, z, w, cfg=current(), plan=plan,
                             acc=acc, bias=bias, residual=residual,
                             dequant=dequant, masks=masks)
 

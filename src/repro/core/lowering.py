@@ -27,7 +27,10 @@ Lowerings register per ``(backend, op_class, ger, fused)`` key:
     operands — four real accumulate-form gers, pp/np, batched or not),
     ``"attn"`` (the canonical three-operand ATTN spec — fused flash
     attention on Pallas with a causal-bounded grid, the chunked two-dot
-    math on xla, the pinned two-contract oracle on ref),
+    math on xla, the pinned two-contract oracle on ref), ``"ssd"`` (the
+    canonical SSD spec — the Mamba2 chunk scan's intra-chunk branch, one
+    fused kernel on Pallas, the decay-mask-and-two-gemm chain on xla and
+    ref),
     ``"einsum"`` (general contraction fallback).
   * ``ger``/``fused``: optional specializations; lookup falls back from the
     most specific key to ``(backend, op_class, None, None)``.
@@ -90,6 +93,12 @@ ACC = "acc"
 # value).  Tests assert on deltas (e.g. "MoE expert dots reached the Pallas
 # gemm path"); reset with ``DISPATCH_COUNTS.clear()``.
 DISPATCH_COUNTS: collections.Counter = collections.Counter()
+# Of those, the dispatches a shape rule sent from pallas to xla (attn block
+# plans the TPU tiling refuses, ssd shapes the kernel does not tile), per
+# (op_class, ger value): the facility's own choice, where any other xla
+# dispatch under a Pallas config is a fallback.  Cleared with
+# ``DISPATCH_COUNTS``.
+SHAPE_RULE_FALLBACKS: collections.Counter = collections.Counter()
 
 
 def _dispatch_scope(op_class: str, backend: str):
@@ -175,6 +184,18 @@ ATTN_Q_CHUNK = 1024
 
 # Families the fused kernel accepts: float operands, f32 accumulator.
 _ATTN_GERS = (Ger.F32GER, Ger.BF16GER2, Ger.F16GER2)
+
+# ----------------------------------------------------------------------
+# SSD spec: the intra-chunk branch of Mamba2's chunked scan (arXiv
+# 2405.21060) — C (B, NC, L, N), B (B, NC, L, N), x (B, NC, L, H, P) and
+# cum, the (B, NC, H, L) within-chunk cumulative log-decays whose
+# differences make the causal 1-semiseparable mask:
+# y[l, h] = sum_{s <= l} (C_l . B_s) exp(cum[h, l] - cum[h, s]) x[s, h].
+# Like attention, the mask couples two contractions, so the facility
+# names it architecturally.
+# ----------------------------------------------------------------------
+
+SSD = "bcln,bcsn,bcshp,bchl->bclhp"
 
 # Families whose kernels the TPU compiler takes (execute's dtype rule;
 # expansion hooks count as the family they chain).
@@ -560,6 +581,9 @@ class Op:
     window: int | None = None
     q_offset: int = 0
     q_chunk: int = 0
+    # ssd op-class (x, y, z = C, B, x): the (B, NC, H, L) within-chunk
+    # cumulative log-decays.
+    w: jnp.ndarray | None = None
 
     @property
     def fused(self) -> bool:
@@ -1516,6 +1540,96 @@ def _lower_ref_attn(op: Op):
     return out.astype(op.out_dtype) if op.out_dtype is not None else out
 
 
+# ---- ssd op-class (the SSD chunk scan's intra-chunk branch) ----------
+# Pallas runs one kernel per (sequence, chunk, head block) that keeps the
+# scores, the decays and their product in VMEM; xla and ref run the chain
+# the models used to inline — the (L, L) decay mask, the C·Bᵀ scores as a
+# gemm, their product, and the gemm with x — on that backend's gemm.
+
+def ssd_shape_fits(l: int, n: int, h: int, p: int, *, ger: Ger,
+                   c_dtype, x_dtype, out_dtype) -> bool:
+    """Shape rule of the kernel at chunk ``l``, state ``n`` and ``h``
+    heads of width ``p``: whole 128-row tiles, head blocks whose x/y
+    lanes are whole 128-lane tiles, heads of a width that tiles the
+    lanes, a float family that runs as one pass with an f32 accumulator,
+    and a working set within the VMEM budget."""
+    from repro.core import tiling as _tiling
+    from repro.kernels import mma_ssd as _ssd
+    hb = _ssd.ssd_head_block(h)
+    if (ger not in _ATTN_GERS or l % _ssd.ROW_TILE
+            or (hb * p) % _ssd.LANES
+            or (p % _ssd.LANES and _ssd.LANES % p)):
+        return False
+    return _ssd.ssd_vmem_bytes(
+        l, n, hb, p, in_bytes=jnp.dtype(c_dtype).itemsize,
+        x_bytes=jnp.dtype(x_dtype).itemsize,
+        out_bytes=jnp.dtype(out_dtype).itemsize) <= _tiling.VMEM_BUDGET
+
+
+def _ssd_fits(op: Op, heads: int | None = None) -> bool:
+    """:func:`ssd_shape_fits` for ``op``, at ``heads`` heads (default:
+    all of x's)."""
+    l, n = op.x.shape[2:]
+    h, p = op.z.shape[3:]
+    return ssd_shape_fits(l, n, heads or h, p, ger=op.ger,
+                          c_dtype=op.x.dtype, x_dtype=op.z.dtype,
+                          out_dtype=op.out_dtype)
+
+
+# The kernel's custom call takes this function's name.  The branch's work
+# is two contractions (C·Bᵀ and the decayed scores times x), so the name
+# carries "gemm", as `_pallas_gemm_impl`'s does: a trace's reader classes
+# the kernel's time with the contractions whose FLOPs it computes.
+@functools.partial(jax.jit, static_argnames=(
+    "kind", "out_dtype", "interpret"))
+def _pallas_gemm_ssd_impl(c, b, x, cum, *, kind, out_dtype, interpret):
+    from repro.kernels import mma_ssd as _ssd
+    pol = precision.policy(kind)
+    return _ssd.mma_ssd(
+        c, b, x, cum, c_dtype=pol.x_dtype, b_dtype=pol.y_dtype,
+        att_dtype=x.dtype, prod_dtype=pol.x_dtype, x_dtype=pol.y_dtype,
+        out_dtype=out_dtype, interpret=interpret)
+
+
+@register("pallas", "ssd")
+def _lower_pallas_ssd(op: Op):
+    return _pallas_gemm_ssd_impl(op.x, op.y, op.z, op.w, kind=op.ger,
+                                 out_dtype=op.out_dtype,
+                                 interpret=op.interpret)
+
+
+def _ssd_chain(op: Op, gemm):
+    """The intra-chunk branch as XLA ops: the causal decay mask from the
+    cumulative sums' differences, the scores ``C·Bᵀ`` in f32, their
+    product, and the decayed scores — rounded to x's dtype — times x, each
+    contraction through ``gemm`` (a registered gemm lowering)."""
+    c, b, x, cum = op.x, op.y, op.z, op.w
+    l = cum.shape[-1]
+    diff = cum[..., :, None] - cum[..., None, :]
+    mask = jnp.tril(jnp.ones((l, l), bool), 0)
+    decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))     # (b,nc,h,L,L)
+
+    def contract(spec, u, v, out_dtype):
+        return gemm(dataclasses.replace(
+            op, x=u, y=v, z=None, w=None, spec=spec, out_dtype=out_dtype,
+            parsed=parse_spec(spec, u.ndim, v.ndim)))
+
+    scores = contract("bcln,bcsn->bcls", c, b, jnp.float32)   # (b,nc,L,L)
+    att = scores[:, :, None] * decay                          # (b,nc,h,L,L)
+    return contract("bchls,bcshp->bclhp", att.astype(x.dtype), x,
+                    op.out_dtype)
+
+
+@register("xla", "ssd")
+def _lower_xla_ssd(op: Op):
+    return _ssd_chain(op, _lower_xla_gemm)
+
+
+@register("ref", "ssd")
+def _lower_ref_ssd(op: Op):
+    return _ssd_chain(op, _lower_ref_gemm)
+
+
 # ---- general einsum fallback -----------------------------------------
 
 @register("xla", "einsum")
@@ -1772,7 +1886,8 @@ def _guarded_dispatch(op: "Op", op_class: str, backend: str, ger: Ger,
 # global checksums carry verification.
 # ----------------------------------------------------------------------
 
-_SHARD_OPERANDS = ("x", "y", "z", "acc", "bias", "residual", "valid")
+_SHARD_OPERANDS = ("x", "y", "z", "acc", "bias", "residual", "valid",
+                   "w")
 
 
 def _shard_rules(plan: Plan):
@@ -1970,6 +2085,32 @@ def _plan_attn_shards(op: Op, rules) -> _ShardPlan | None:
         seq_axes=seq_axes, seq_parts=seq_parts, seq_local=seq_local)
 
 
+def _plan_ssd_shards(op: Op, rules) -> _ShardPlan | None:
+    """SSD shards the sequences over the data axes and heads over the
+    ssm-heads axis, where a shard's heads still tile the kernel; every
+    (sequence, chunk, head) is independent, so each shard's kernel
+    computes exactly the single-device values.  None where no axis
+    divides: the kernel then runs on the whole operands."""
+    s, h = op.x.shape[0], op.z.shape[3]
+    dp = rules.rules.get("batch")
+    hp = rules.rules.get("ssm_heads")
+    sa = (dp if dp is not None and rules.axis_extent(dp) > 1
+          and s % rules.axis_extent(dp) == 0 else None)
+    ha = (hp if hp is not None and rules.axis_extent(hp) > 1
+          and h % rules.axis_extent(hp) == 0
+          and not set(_ax_flat(hp)) & set(_ax_flat(sa or ()))
+          and _ssd_fits(op, heads=h // rules.axis_extent(hp)) else None)
+    if sa is None and ha is None:
+        return None
+    cb = _P(sa, None, None, None)
+    xy = _P(sa, None, None, ha, None)
+    return _ShardPlan(
+        mesh=rules.mesh,
+        in_specs=(cb, cb, xy, _P(), _P(), _P(), _P(),
+                  _P(sa, None, ha, None)),
+        out_spec=xy)
+
+
 def _shard_plan(op: Op, op_class: str, rules) -> _ShardPlan | None:
     if op_class == "gemm":
         return _plan_gemm_shards(op, rules)
@@ -1977,6 +2118,8 @@ def _shard_plan(op: Op, op_class: str, rules) -> _ShardPlan | None:
         return _plan_conv_shards(op, rules)
     if op_class == "attn":
         return _plan_attn_shards(op, rules)
+    if op_class == "ssd":
+        return _plan_ssd_shards(op, rules)
     return None
 
 
@@ -2100,7 +2243,8 @@ def _admit_packed(op_class: str, backend: str, ger: Ger, pol, parsed,
 # The driver
 # ----------------------------------------------------------------------
 
-def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
+def execute(spec: str, x, y, z=None, w=None, *, cfg,
+            plan: Plan | None = None,
             acc=None, bias=None, residual=None,
             dequant: Dequant | None = None, masks=None):
     """Resolve ``plan`` against ``cfg``, pick a lowering, run it.
@@ -2111,9 +2255,10 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
     M/N/K axes (each entry optional) — routes to the ``gemm.masked``
     op-class, where the Pallas lowering applies them to the streamed
     panels in-kernel instead of pre-masking operands in HBM.  ``z`` is the
-    value operand of the canonical ``ATTN`` spec (the one three-operand
+    value operand of the canonical ``ATTN`` spec (a three-operand
     builtin); for attn, ``masks`` is the 1-tuple ``(valid,)`` KV-slot
-    predicate.
+    predicate.  The canonical ``SSD`` spec takes four operands: C, B, x as
+    ``z`` and the cumulative log-decays as ``w``.
     """
     from repro.kernels import epilogue as _epilogue
 
@@ -2148,11 +2293,38 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
     stride: tuple[int, ...] = ()
     parsed = None
     valid = None
-    if z is not None and spec != ATTN:
+    if z is not None and spec not in (ATTN, SSD):
         raise ValueError(
-            f"a third operand is attn-spec vocabulary "
-            f"(facility.ATTN), not {spec!r}")
-    if spec == ATTN:
+            f"a third operand is attn-spec vocabulary (facility.ATTN) "
+            f"or ssd-spec vocabulary (facility.SSD), not {spec!r}")
+    if w is not None and spec != SSD:
+        raise ValueError(
+            f"a fourth operand is ssd-spec vocabulary (facility.SSD), "
+            f"not {spec!r}")
+    if spec == SSD:
+        op_class = "ssd"
+        if z is None or w is None:
+            raise ValueError(
+                f"the ssd spec {spec!r} is a four-operand contraction: "
+                f"contract(facility.SSD, C, B, x, cum)")
+        s, nc, l, n = jnp.shape(x)
+        if (jnp.ndim(z) != 5 or jnp.shape(y) != (s, nc, l, n)
+                or jnp.shape(z)[:3] != (s, nc, l)
+                or jnp.shape(w) != (s, nc, jnp.shape(z)[3], l)):
+            raise ValueError(
+                f"ssd wants C, B (S, NC, L, N), x (S, NC, L, H, P) and cum "
+                f"(S, NC, H, L); got {jnp.shape(x)}, {jnp.shape(y)}, "
+                f"{jnp.shape(z)}, {jnp.shape(w)}")
+        if (acc is not None or dequant is not None or masks is not None
+                or plan.saturating
+                or plan.neg_product or plan.neg_acc or plan.alpha != 1.0
+                or plan.beta != 1.0 or not ep.is_identity
+                or plan.block is not None):
+            raise ValueError(
+                "ssd contractions take no accumulator seed, dequant, "
+                "masks, epilogue, block, saturating or accumulate forms — "
+                "only the ger family and the out dtype")
+    elif spec == ATTN:
         op_class = "attn"
         if z is None:
             raise ValueError(
@@ -2329,16 +2501,19 @@ def execute(spec: str, x, y, z=None, *, cfg, plan: Plan | None = None,
             alpha=plan.alpha, beta=plan.beta, backend=backend,
             stride=stride, padding=plan.padding, masks=masks,
             z=z, valid=valid, causal=plan.causal, window=plan.window,
-            q_offset=plan.q_offset, q_chunk=plan.q_chunk)
-    if (op_class == "attn" and backend == "pallas" and not interpret
-            and not _attn_tiles_fit(op)):
-        # Shape rule: attn block plans the TPU tiling refuses (see
-        # _attn_tiles_fit) take the XLA lowering, before counting.
+            q_offset=plan.q_offset, q_chunk=plan.q_chunk, w=w)
+    if backend == "pallas" and (
+            (op_class == "attn" and not interpret and not _attn_tiles_fit(op))
+            or (op_class == "ssd" and not _ssd_fits(op))):
+        # Shape rules: attn block plans the TPU tiling refuses (see
+        # _attn_tiles_fit), and ssd shapes the kernel does not tile, take
+        # the XLA lowering, before counting.
         backend = "xla"
         fn = lookup(backend, op_class, ger, not ep.is_identity)
         op = dataclasses.replace(op, backend=backend)
+        SHAPE_RULE_FALLBACKS[(op_class, ger.value)] += 1
     wrap = None
-    if backend == "pallas" and op_class in ("gemm", "conv", "attn"):
+    if backend == "pallas" and op_class in ("gemm", "conv", "attn", "ssd"):
         srules = _shard_rules(plan)
         if srules is not None:
             sp = _shard_plan(op, op_class, srules)

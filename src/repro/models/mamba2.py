@@ -101,15 +101,6 @@ def _causal_conv(xbc, conv_w, conv_b, conv_state=None):
         return out, state
 
 
-def _segsum(dA):
-    """Stable segment-sum: out[..., i, j] = sum dA[..., j+1..i] (j < i)."""
-    l = dA.shape[-1]
-    cs = jnp.cumsum(dA, axis=-1)
-    diff = cs[..., :, None] - cs[..., None, :]
-    mask = jnp.tril(jnp.ones((l, l), bool), 0)
-    return jnp.where(mask, diff, -jnp.inf)
-
-
 def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
     """SSD scan (ssd_minimal_discrete, Mamba2 paper listing 1).
 
@@ -132,13 +123,10 @@ def ssd_chunked(x, dt, A, B, C, D, chunk, return_state: bool = False):
     dAc = dAc.transpose(0, 1, 3, 2)                       # (b,nc,h,L)
     dA_cum = jnp.cumsum(dAc, axis=-1)                     # (b,nc,h,L)
 
-    # 1) intra-chunk (the "quadratic attention" branch of the duality)
-    L = jnp.exp(_segsum(dAc))                             # (b,nc,h,L,L)
-    scores = facility.contract("bcln,bcsn->bcls", Cc, Bc,
-                               plan=Plan(out_dtype=jnp.float32))  # (b,nc,L,L)
-    att = scores[:, :, None] * L                          # (b,nc,h,L,L)
-    y_intra = facility.contract("bchls,bcshp->bclhp",
-                                att.astype(x.dtype), xc)
+    # 1) intra-chunk (the "quadratic attention" branch of the duality):
+    # scores C·Bᵀ under the causal mask exp(cum_l - cum_s), times x
+    y_intra = facility.contract(facility.SSD, Cc, Bc, xc,
+                                dA_cum)                   # (b,nc,L,h,p)
 
     # 2) chunk states: decayed outer products B^T (dt x)
     decay_states = jnp.exp(dA_cum[..., -1:] - dA_cum)     # (b,nc,h,L)

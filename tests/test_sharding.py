@@ -221,3 +221,21 @@ def test_pipeline_chunked_matches_fused():
     assert len(probe.fired(faults.COLLECTIVE)) == 4
     print("OK")
     """)
+
+
+def test_ssd_bitwise_under_mesh():
+    _run(_PRELUDE + """
+    def ssd(s, h, p):
+        c, b = arr(s, 2, 128, 16), arr(s, 2, 128, 16)
+        x = arr(s, 2, 128, h, p)
+        cum = jnp.cumsum(-jnp.abs(arr(s, 2, h, 128)) * 0.1, -1)
+        return lambda: facility.contract(facility.SSD, c, b, x, cum,
+                                         plan=PAL)
+    # sequences over data, heads over model: 8 heads of 16 a shard
+    check("ssd", ssd(4, 32, 16))
+    # 2 heads a shard would not tile the kernel's lanes: sequences only
+    check("ssd_seq_only", ssd(4, 8, 16))
+    # nothing divides: the kernel runs on the whole operands
+    check("ssd_indivisible", ssd(3, 6, 64), want_collective=False)
+    print("OK")
+    """)

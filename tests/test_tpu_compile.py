@@ -95,6 +95,22 @@ def test_batched_ssd_gemm(one_chip):
         b, x) >= 1
 
 
+@pytest.mark.parametrize("shape", [(2, 16, 256, 64, 56, 64),
+                                   (64, 1, 256, 128, 24, 64)],
+                         ids=["zamba2-7b", "mamba2-130m"])
+def test_ssd_intra_chunk_kernel(one_chip, shape):
+    """The SSD chunk scan's intra-chunk branch as one kernel: one layer of
+    a zamba2-7b 4096-token prompt (two B/C groups as sequences, 16 chunks
+    of 256, 56 heads of 64 a group, state 64), and mamba2-130m's prefill
+    of 64 prompts of 256 (24 heads, state 128)."""
+    s, nc, l, n, h, p = shape
+    c, b = _spec(one_chip, (s, nc, l, n)), _spec(one_chip, (s, nc, l, n))
+    x = _spec(one_chip, (s, nc, l, h, p))
+    cum = _spec(one_chip, (s, nc, h, l), jnp.float32)
+    assert _custom_calls(lambda c_, b_, x_, m: facility.contract(
+        facility.SSD, c_, b_, x_, m), c, b, x, cum) == 1
+
+
 def test_mamba2_depthwise_conv(one_chip):
     """The causal width-4 depthwise conv over mamba2-130m's 1792 conv
     channels, on the f32 accumulator path with bias + silu fused."""
